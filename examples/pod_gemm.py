@@ -1,32 +1,32 @@
-"""The paper's workload on the TPU mesh: out-of-core distributed GEMM
+"""The paper's workload on a device mesh: out-of-core distributed GEMM
 with the BLASX ring schedule (L2-cache/overlap insight on ICI).
 
-Spawns with 8 host devices (this example re-execs itself with XLA_FLAGS
-if needed) and compares the ring collective-matmul against the plain
-GSPMD lowering: same numerics, collective-permute (neighbor) traffic
-instead of monolithic all-gathers.
+Builds a 2-D mesh from the devices that exist and compares the ring
+collective-matmul against the plain GSPMD lowering: same numerics,
+collective-permute (neighbor) traffic instead of monolithic
+all-gathers.  On a CPU host, ask XLA for several devices:
 
-Run:  PYTHONPATH=src python examples/pod_gemm.py
+Run:  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+      PYTHONPATH=src python examples/pod_gemm.py
 """
-import os
-import sys
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-if "--respawned" not in sys.argv and "xla_force_host_platform_device_count" \
-        not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
-                               + os.environ.get("XLA_FLAGS", ""))
-    os.execv(sys.executable, [sys.executable] + sys.argv + ["--respawned"])
+from repro.api import BlasxContext
+from repro.core import distributed as dist
 
-import jax                      # noqa: E402
-import jax.numpy as jnp        # noqa: E402
-import numpy as np             # noqa: E402
 
-from repro.api import BlasxContext  # noqa: E402
-from repro.core import distributed as dist  # noqa: E402
+def mesh_shape(n_devices: int):
+    """(rows, cols) for the ("data", "model") mesh: two rows once there
+    are at least four devices, one ring of all of them otherwise."""
+    rows = 2 if n_devices >= 4 and n_devices % 2 == 0 else 1
+    return rows, n_devices // rows
 
 
 def main():
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh(mesh_shape(len(jax.devices())), ("data", "model"))
+    print(f"mesh {dict(mesh.shape)} on {jax.devices()[0].platform}")
     rng = np.random.default_rng(0)
     A = jnp.asarray(rng.standard_normal((512, 1024)), jnp.float32)
     B = jnp.asarray(rng.standard_normal((1024, 768)), jnp.float32)

@@ -51,9 +51,12 @@ def _group_contract():
         a2 = jnp.transpose(a, (0, 2, 1, 3)).reshape(g, m, s * k)
         b2 = b.reshape(g, s * k, n)
         # f32 accumulation for every sub-f64 storage dtype (f32, bf16,
-        # f16); the caller casts back to the storage dtype afterwards
+        # f16); the caller casts back to the storage dtype afterwards.
+        # HIGHEST: on a TPU the default precision multiplies f32
+        # operands in one bf16 pass, which is not an f32 GEMM
         pref = jnp.float64 if a.dtype == jnp.float64 else jnp.float32
-        return jnp.matmul(a2, b2, preferred_element_type=pref)
+        return jnp.matmul(a2, b2, preferred_element_type=pref,
+                          precision=jax.lax.Precision.HIGHEST)
 
     return run
 

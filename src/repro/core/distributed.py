@@ -34,9 +34,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
-
-from ..kernels.pallas_compat import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def _acc_type(a_dtype, b_dtype):
@@ -60,9 +58,7 @@ def ring_allgather_matmul(x_local: jax.Array, w_local: jax.Array,
     device matmuls the panel it currently holds — panel k+1 is in
     flight (ppermute) while panel k multiplies.
     """
-    # psum of a literal folds to a static int on every jax version;
-    # lax.axis_size only exists on newer releases
-    d = lax.psum(1, axis_name)
+    d = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     m_local, _ = x_local.shape
     n_local = w_local.shape[1]
@@ -101,9 +97,7 @@ def ring_reduce_scatter_matmul(x_local: jax.Array, w_local: jax.Array,
     rows whose tail is zeros; callers slice (``tp_matmul`` /
     ``distributed_gemm`` do).
     """
-    # psum of a literal folds to a static int on every jax version;
-    # lax.axis_size only exists on newer releases
-    d = lax.psum(1, axis_name)
+    d = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     m = x_local.shape[0]
     mb = -(-m // d)
@@ -134,7 +128,7 @@ def gspmd_allgather_matmul(x_local, w_local, axis_name):
 
 
 def gspmd_reduce_scatter_matmul(x_local, w_local, axis_name):
-    d = lax.psum(1, axis_name)
+    d = lax.axis_size(axis_name)
     m = x_local.shape[0]
     mb = -(-m // d)
     if mb * d != m:  # same pad-and-slice contract as the ring twin
@@ -202,14 +196,18 @@ def distributed_gemm(A: jax.Array, B: jax.Array, mesh: Mesh, *,
                 jnp.promote_types(a_blk.dtype, b_blk.dtype))
         return y
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(row_axis, col_axis), P(col_axis, None)),
         out_specs=P(row_axis, None),
-        check_rep=False,
+        check_vma=False,
     )
     C = fn(A, B)
-    return C[:m] if m_pad != m else C
+    if m_pad == m:
+        return C
+    # M rows cannot split evenly over the row axis, so the sliced
+    # result is replicated; explicit-axis meshes need that spelled out
+    return C.at[:m].get(out_sharding=NamedSharding(mesh, P(None, None)))
 
 
 def tp_matmul(x: jax.Array, w: jax.Array, mesh: Mesh, *, axis: str = "model",
@@ -238,20 +236,25 @@ def tp_matmul(x: jax.Array, w: jax.Array, mesh: Mesh, *, axis: str = "model",
         def body(xl, wl):
             x2 = xl.reshape(-1, xl.shape[-1])
             y = ag(x2, wl, axis)
-            return y.reshape(xl.shape[0], -1, wl.shape[1])
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(P(bspec, axis, None), P(None, axis)),
-                       out_specs=P(bspec, None, axis), check_rep=False)
-        y = fn(x, w)
-        return y[:, :s] if s_pad != s else y
+            # every shard holds the whole gathered sequence: drop the
+            # padding here, where no sharding has to be resolved
+            return y.reshape(xl.shape[0], -1, wl.shape[1])[:, :s]
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(bspec, axis, None), P(None, axis)),
+                           out_specs=P(bspec, None, axis), check_vma=False)
+        return fn(x, w)
     elif kind == "row":
         def body(xl, wl):
             x2 = xl.reshape(-1, xl.shape[-1])
             y = rs(x2, wl, axis)
             return y.reshape(xl.shape[0], -1, wl.shape[1])
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(P(bspec, None, axis), P(axis, None)),
-                       out_specs=P(bspec, axis, None), check_rep=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P(bspec, None, axis), P(axis, None)),
+                           out_specs=P(bspec, axis, None), check_vma=False)
         y = fn(x, w)
-        return y[:, :s] if s_pad != s else y
+        if s_pad == s:
+            return y
+        # a ragged sequence cannot stay split over ``axis``
+        return y.at[:, :s].get(
+            out_sharding=NamedSharding(mesh, P(bspec, None, None)))
     raise ValueError(f"kind must be column|row, got {kind}")

@@ -45,7 +45,7 @@ from .heap import BlasxHeap
 from . import task as taskmod
 from .task import KIND_FIXUP, KIND_PARTIAL, Ledger, Task, TileRef
 from .taskqueue import ReadyQueue, ReservationStation
-from .tile_kernels import get_solver, materialize
+from .tile_kernels import materialize, solve_triangular
 from .tiling import TiledMatrix, TileKey
 
 # paper Table IV: measured DMA throughputs on Everest
@@ -360,7 +360,6 @@ class BlasxRuntime:
         self.devices = [DeviceSim(d, cfg, self.directory)
                         for d in range(cfg.n_devices)]
         self.backend = create_backend(cfg.backend)
-        self._solver = get_solver()
         self.runs = 0
         # serving front-end state (repro.serve): which tenant the
         # in-flight run belongs to (tags ALRU blocks for the quota
@@ -889,9 +888,9 @@ class BlasxRuntime:
                 h, w = out_grid.grid.tile_shape(t.i, t.j)
                 acc = np.zeros((h, w), dtype=out_grid.data.dtype)
             if t.finalize is not None:  # TRSM
-                result = self._solver(rec.diag, t.alpha * rec.rhs - acc,
-                                      lower=t.finalize.lower,
-                                      unit_diag=t.finalize.unit_diag)
+                result = solve_triangular(rec.diag, t.alpha * rec.rhs - acc,
+                                          lower=t.finalize.lower,
+                                          unit_diag=t.finalize.unit_diag)
             else:
                 result = t.alpha * acc
                 if rec.cin is not None:

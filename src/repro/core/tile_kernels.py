@@ -12,6 +12,7 @@ either way.
 from __future__ import annotations
 
 import numpy as np
+import scipy.linalg
 
 from .task import (FILL_FULL, FILL_SYM_L, FILL_SYM_U, FILL_TRI_L,
                    FILL_TRI_LU, FILL_TRI_U, FILL_TRI_UU, TileRef)
@@ -50,35 +51,5 @@ def materialize(tile: np.ndarray, ref: TileRef) -> np.ndarray:
 def solve_triangular(a: np.ndarray, b: np.ndarray, lower: bool,
                      unit_diag: bool) -> np.ndarray:
     """Tile-level triangular solve for the TRSM finalize step."""
-    import scipy.linalg  # local import; only TRSM needs it
-
     return scipy.linalg.solve_triangular(
         a, b, lower=lower, unit_diagonal=unit_diag, check_finite=False)
-
-
-def solve_triangular_np(a: np.ndarray, b: np.ndarray, lower: bool,
-                        unit_diag: bool) -> np.ndarray:
-    """Pure-numpy fallback when scipy is unavailable: forward/back
-    substitution at tile granularity (row blocks of 1)."""
-    n = a.shape[0]
-    x = np.array(b, dtype=np.promote_types(a.dtype, b.dtype), copy=True)
-    rng = range(n) if lower else range(n - 1, -1, -1)
-    for r in rng:
-        if lower:
-            if r > 0:
-                x[r] -= a[r, :r] @ x[:r]
-        else:
-            if r < n - 1:
-                x[r] -= a[r, r + 1:] @ x[r + 1:]
-        if not unit_diag:
-            x[r] /= a[r, r]
-    return x
-
-
-def get_solver():
-    try:
-        import scipy.linalg  # noqa: F401
-
-        return solve_triangular
-    except ImportError:  # pragma: no cover
-        return solve_triangular_np

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import COMPILER_PARAMS as _COMPILER_PARAMS
 
 NEG_INF = -1e30
 
@@ -121,7 +120,7 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denom
             pltpu.VMEM((block_q, d), jnp.float32),   # output accumulator
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

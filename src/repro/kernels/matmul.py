@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import COMPILER_PARAMS as _COMPILER_PARAMS
 
 ACTIVATIONS = {
     None: lambda x: x,
@@ -32,6 +31,15 @@ ACTIVATIONS = {
     "silu": jax.nn.silu,
     "tanh": jnp.tanh,
 }
+
+
+def _dot_precision(a_ref, b_ref):
+    """Full-precision passes for f32 operands: Mosaic's default
+    contract precision is not guaranteed to be f32 on the MXU.  Half
+    precisions need no extra passes."""
+    if jnp.float32 in (a_ref.dtype, b_ref.dtype):
+        return jax.lax.Precision.HIGHEST
+    return None
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int,
@@ -45,7 +53,8 @@ def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=_dot_precision(a_ref, b_ref))
 
     @pl.when(k == n_k - 1)
     def _store():
@@ -62,7 +71,8 @@ def _matmul_bias_kernel(a_ref, b_ref, bias_ref, o_ref, acc_ref, *, n_k: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     acc_ref[...] += jnp.dot(a_ref[...], b_ref[...],
-                            preferred_element_type=jnp.float32)
+                            preferred_element_type=jnp.float32,
+                            precision=_dot_precision(a_ref, b_ref))
 
     @pl.when(k == n_k - 1)
     def _store():
@@ -105,7 +115,7 @@ def matmul_pallas(a: jax.Array, b: jax.Array, bias: Optional[jax.Array],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

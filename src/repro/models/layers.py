@@ -138,16 +138,15 @@ def row_parallel_matmul(x: jax.Array, w: jax.Array,
         return x @ w
     from jax.sharding import PartitionSpec as P
 
-    from ..kernels.pallas_compat import shard_map
     bspec = rules.physical("batch")
 
     def body(xl, wl):
         part = jnp.dot(xl, wl, preferred_element_type=jnp.float32)
         return jax.lax.psum(part.astype(x.dtype), ax)
 
-    fn = shard_map(body, mesh=rules.mesh,
-                   in_specs=(P(bspec, None, ax), P(ax, None)),
-                   out_specs=P(bspec, None, None), check_rep=False)
+    fn = jax.shard_map(body, mesh=rules.mesh,
+                       in_specs=(P(bspec, None, ax), P(ax, None)),
+                       out_specs=P(bspec, None, None), check_vma=False)
     return fn(x, w)
 
 

@@ -108,8 +108,6 @@ def _moe_block_sharded(cfg, p: dict, x: jax.Array, rules: MeshRules,
     combined by a single psum over the model axis."""
     from jax.sharding import PartitionSpec as P
 
-    from ..kernels.pallas_compat import shard_map
-
     B, S, d = x.shape
     E, K = cfg.n_experts, cfg.top_k
     N = B * S
@@ -153,13 +151,13 @@ def _moe_block_sharded(cfg, p: dict, x: jax.Array, rules: MeshRules,
                      ).reshape(-1, K, xl.shape[1]), axis=1)
         return jax.lax.psum(y.astype(xl.dtype), model_ax)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=rules.mesh,
         in_specs=(P(dspec, None), P(dspec, None), P(dspec, None),
                   P(model_ax, None, None), P(model_ax, None, None),
                   P(model_ax, None, None)),
         out_specs=P(dspec, None),
-        check_rep=False,
+        check_vma=False,
     )
     y = fn(xt, gate_w, gate_idx, p["w_gate"], p["w_up"], p["w_down"])
     if cfg.n_shared_experts:

@@ -218,7 +218,7 @@ def compressed_psum(grads: Any, err_state: Any, axis_name: str
     def one(g, e):
         q, scale, new_e = compress_int8(g, e)
         summed = jax.lax.psum(decompress_int8(q, scale), axis_name)
-        n = jax.lax.psum(1, axis_name)  # static; axis_size needs newer jax
+        n = jax.lax.axis_size(axis_name)
         return summed / n, new_e
     pairs = jax.tree.map(one, grads, err_state)
     g2 = jax.tree.map(lambda t: t[0], pairs,

@@ -122,7 +122,6 @@ jax.config.update("jax_enable_x64", True)
 import numpy as np, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.core import distributed as dist
-from repro.kernels.pallas_compat import shard_map
 mesh = jax.make_mesh((2, 4), ("data", "model"))
 ring = jax.make_mesh((8,), ("r",))
 rng = np.random.default_rng(7)
@@ -137,13 +136,13 @@ for dtype, tol in TOL.items():
     ag = {}
     rs = {}
     for mode, (ag_fn, rs_fn) in dist.MODES.items():
-        f = shard_map(lambda a, b: ag_fn(a, b, "r"), mesh=ring,
-                      in_specs=(P("r", None), P(None, "r")),
-                      out_specs=P(None, "r"), check_rep=False)
+        f = jax.shard_map(lambda a, b: ag_fn(a, b, "r"), mesh=ring,
+                          in_specs=(P("r", None), P(None, "r")),
+                          out_specs=P(None, "r"), check_vma=False)
         ag[mode] = np.asarray(f(A, B), np.float32)
-        f = shard_map(lambda a, b: rs_fn(a, b, "r"), mesh=ring,
-                      in_specs=(P(None, "r"), P("r", None)),
-                      out_specs=P("r", None), check_rep=False)
+        f = jax.shard_map(lambda a, b: rs_fn(a, b, "r"), mesh=ring,
+                          in_specs=(P(None, "r"), P("r", None)),
+                          out_specs=P("r", None), check_vma=False)
         rs[mode] = np.asarray(f(A, B), np.float32)
     for kind in (ag, rs):
         assert np.abs(kind["ring"] - want).max() < tol, (dtype, tol)

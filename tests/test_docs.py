@@ -73,6 +73,7 @@ _RUNNABLES = {
     "benchmarks.pod": "benchmarks.pod",
     "repro.serve": "repro.serve.__main__",
     "repro.analysis": "repro.analysis",
+    "chip_smoke.py": "chip_smoke",
 }
 
 
