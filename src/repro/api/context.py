@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import itertools
 import json
 import threading
@@ -46,6 +47,7 @@ from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
+from .. import telemetry
 from ..core import task as taskmod
 from ..core.dtypes import (SUPPORTED_DTYPES, canonical_dtype,
                            promote_dtypes, validate_backend_dtype)
@@ -63,6 +65,19 @@ ArrayLike = Union[np.ndarray, "MatrixHandle"]
 
 # one global id stream so handles never alias across contexts either
 _MATRIX_IDS = itertools.count()
+
+
+def _api_call(method):
+    """Runs a public routine inside a ``blasx.call`` span (its self time:
+    argument checks and the ``CallRecord`` snapshot)."""
+    routine = method.__name__
+
+    @functools.wraps(method)
+    def call(self, *args, **kwargs):
+        with telemetry.span("blasx.call", routine=routine):
+            return method(self, *args, **kwargs)
+
+    return call
 
 
 def _as2d(x, name: str, dtype=None) -> np.ndarray:
@@ -128,7 +143,14 @@ class MatrixHandle:
 @dataclasses.dataclass(frozen=True)
 class CallRecord:
     """Ledger snapshot of one routine executed by a context (deltas
-    against the runtime's cumulative counters)."""
+    against the runtime's cumulative counters).
+
+    The bytes and ``makespan`` are the runtime's model, not measurements:
+    ``h2d_bytes`` counts the tile-cache misses the model charges (on a
+    TPU every step group stages all of its tiles anew; the bytes handed
+    to the device are the ``h2d_bytes`` counter of ``repro.telemetry``),
+    and ``makespan`` is the sim engine's clock, from K40c/PCIe constants.
+    """
 
     index: int
     routine: str
@@ -409,21 +431,24 @@ class BlasxContext:
                 raise ValueError(
                     f"{name}: handle tile {x.tile} != requested tile {tile}")
             return self._adopt(x, dtype if strict else None, name)
-        a = _as2d(x, name, dtype)
-        # pass the resolved dtype through: tile() would otherwise
-        # re-resolve against the context default and recast a per-call
-        # dtype= override (None stays None -> tile applies the default)
-        h = self.tile(a, tile or self.tile_size, dtype=dtype)
+        with telemetry.span("blasx.prep"):
+            a = _as2d(x, name, dtype)
+            # pass the resolved dtype through: tile() would otherwise
+            # re-resolve against the context default and recast a
+            # per-call dtype= override (None stays None -> tile applies
+            # the default)
+            h = self.tile(a, tile or self.tile_size, dtype=dtype)
         ephemeral.append(h)
         return h
 
     def _fresh_out(self, rows: int, cols: int, tile: int, dtype,
                    seed: Optional[np.ndarray] = None) -> MatrixHandle:
         """New output matrix under a fresh id (seeded from C or zeros)."""
-        if seed is not None:
-            data = np.array(seed, dtype=dtype, copy=True)
-        else:
-            data = np.zeros((rows, cols), dtype=dtype)
+        with telemetry.span("blasx.prep"):
+            if seed is not None:
+                data = np.array(seed, dtype=dtype, copy=True)
+            else:
+                data = np.zeros((rows, cols), dtype=dtype)
         mid = f"M{next(_MATRIX_IDS)}"
         return MatrixHandle(self, TiledMatrix(mid, data, tile))
 
@@ -460,8 +485,9 @@ class BlasxContext:
             sum(b[2] for b in before)
         d_miss = sum(d.alru.misses for d in rt.devices) - \
             sum(b[3] for b in before)
-        for h in ephemeral or ():
-            self._invalidate_matrix(h.matrix_id)
+        with telemetry.span("blasx.prep"):
+            for h in ephemeral or ():
+                self._invalidate_matrix(h.matrix_id)
         rec = CallRecord(
             index=self.n_calls, routine=routine,
             h2d_bytes=after_comm["h2d"] - before_comm["h2d"],
@@ -697,6 +723,7 @@ class BlasxContext:
             return rep
 
     # ======================================================== L3 routines
+    @_api_call
     def gemm(self, A: ArrayLike, B: ArrayLike, C: Optional[ArrayLike] = None,
              *, alpha: float = 1.0, beta: float = 0.0,
              transa: str = "N", transb: str = "N",
@@ -729,13 +756,15 @@ class BlasxContext:
             self._check_exec_dtype(out_dt, Ah.dtype, Bh.dtype)
             out = self._prep_c(C, (m, n), t, out_dt, beta,
                                force=dt is not None)
-            tasks = taskmod.taskize_gemm(Ah.tiled.grid, Bh.tiled.grid,
-                                         out.tiled.grid, transa, transb,
-                                         alpha, beta)
+            with telemetry.span("blasx.plan"):
+                tasks = taskmod.taskize_gemm(Ah.tiled.grid, Bh.tiled.grid,
+                                             out.tiled.grid, transa, transb,
+                                             alpha, beta)
             mats = {h.matrix_id: h.tiled for h in (Ah, Bh, out)}
             self._run("gemm", tasks, mats, out.matrix_id, eph)
             return out
 
+    @_api_call
     def syrk(self, A: ArrayLike, C: Optional[ArrayLike] = None, *,
              alpha: float = 1.0, beta: float = 0.0, uplo: str = "U",
              trans: str = "N", tile: Optional[int] = None,
@@ -756,12 +785,14 @@ class BlasxContext:
             self._check_exec_dtype(out_dt, Ah.dtype)
             out = self._prep_c(C, (n, n), Ah.tile, out_dt, beta,
                                force=dt is not None)
-            tasks = taskmod.taskize_syrk(Ah.tiled.grid, out.tiled.grid,
-                                         uplo, trans, alpha, beta)
+            with telemetry.span("blasx.plan"):
+                tasks = taskmod.taskize_syrk(Ah.tiled.grid, out.tiled.grid,
+                                             uplo, trans, alpha, beta)
             mats = {h.matrix_id: h.tiled for h in (Ah, out)}
             self._run("syrk", tasks, mats, out.matrix_id, eph)
             return out
 
+    @_api_call
     def syr2k(self, A: ArrayLike, B: ArrayLike,
               C: Optional[ArrayLike] = None, *, alpha: float = 1.0,
               beta: float = 0.0, uplo: str = "U", trans: str = "N",
@@ -785,13 +816,15 @@ class BlasxContext:
             self._check_exec_dtype(out_dt, Ah.dtype, Bh.dtype)
             out = self._prep_c(C, (n, n), Ah.tile, out_dt, beta,
                                force=dt is not None)
-            tasks = taskmod.taskize_syr2k(Ah.tiled.grid, Bh.tiled.grid,
-                                          out.tiled.grid, uplo, trans,
-                                          alpha, beta)
+            with telemetry.span("blasx.plan"):
+                tasks = taskmod.taskize_syr2k(Ah.tiled.grid, Bh.tiled.grid,
+                                              out.tiled.grid, uplo, trans,
+                                              alpha, beta)
             mats = {h.matrix_id: h.tiled for h in (Ah, Bh, out)}
             self._run("syr2k", tasks, mats, out.matrix_id, eph)
             return out
 
+    @_api_call
     def symm(self, A: ArrayLike, B: ArrayLike,
              C: Optional[ArrayLike] = None, *, alpha: float = 1.0,
              beta: float = 0.0, side: str = "L", uplo: str = "U",
@@ -815,9 +848,10 @@ class BlasxContext:
             # it never becomes a cached-tile operand.
             self._check_side_r_handles(dtype, A=A, B=B)
             # C = alpha*B*A + beta*C  ==  (alpha*A*B^T + beta*C^T)^T
-            Bt = np.ascontiguousarray(_array_of(B).T)
-            Ct = None if C is None else \
-                np.ascontiguousarray(_as2d(_array_of(C), "C").T)
+            with telemetry.span("blasx.prep"):
+                Bt = np.ascontiguousarray(_array_of(B).T)
+                Ct = None if C is None else \
+                    np.ascontiguousarray(_as2d(_array_of(C), "C").T)
             out = self.symm(_array_of(A), Bt, Ct, alpha=alpha, beta=beta,
                             side="L", uplo=uplo, tile=tile, dtype=dtype)
             return self._transposed_result(out)
@@ -839,12 +873,14 @@ class BlasxContext:
             self._check_exec_dtype(out_dt, Ah.dtype, Bh.dtype)
             out = self._prep_c(C, (m, n), Ah.tile, out_dt, beta,
                                force=dt is not None)
-            tasks = taskmod.taskize_symm(Ah.tiled.grid, Bh.tiled.grid,
-                                         out.tiled.grid, uplo, alpha, beta)
+            with telemetry.span("blasx.plan"):
+                tasks = taskmod.taskize_symm(Ah.tiled.grid, Bh.tiled.grid,
+                                             out.tiled.grid, uplo, alpha, beta)
             mats = {h.matrix_id: h.tiled for h in (Ah, Bh, out)}
             self._run("symm", tasks, mats, out.matrix_id, eph)
             return out
 
+    @_api_call
     def trmm(self, A: ArrayLike, B: ArrayLike, *, alpha: float = 1.0,
              side: str = "L", uplo: str = "U", transa: str = "N",
              diag: str = "N", tile: Optional[int] = None,
@@ -857,10 +893,11 @@ class BlasxContext:
             self._check_side_r_handles(dtype, A=A, B=B)
             # B*op(A) == (op(A)^T B^T)^T — §III-C at matrix granularity
             flip = "T" if transa.upper()[0] == "N" else "N"
-            out = self.trmm(_array_of(A),
-                            np.ascontiguousarray(_array_of(B).T),
-                            alpha=alpha, side="L", uplo=uplo, transa=flip,
-                            diag=diag, tile=tile, dtype=dtype)
+            with telemetry.span("blasx.prep"):
+                Bt = np.ascontiguousarray(_array_of(B).T)
+            out = self.trmm(_array_of(A), Bt, alpha=alpha, side="L",
+                            uplo=uplo, transa=flip, diag=diag, tile=tile,
+                            dtype=dtype)
             return self._transposed_result(out)
         dt = self._resolve_dtype(dtype)
         strict = dtype is not None
@@ -882,13 +919,15 @@ class BlasxContext:
             out = self._fresh_out(m, n, Ah.tile, out_dt)
             # B's tiles are the taskization's Cin inputs: a reused handle
             # serves them straight from the warm cache.
-            tasks = taskmod.taskize_trmm(Ah.tiled.grid, Bh.tiled.grid,
-                                         out.tiled.grid, uplo, transa,
-                                         diag, alpha)
+            with telemetry.span("blasx.plan"):
+                tasks = taskmod.taskize_trmm(Ah.tiled.grid, Bh.tiled.grid,
+                                             out.tiled.grid, uplo, transa,
+                                             diag, alpha)
             mats = {h.matrix_id: h.tiled for h in (Ah, Bh, out)}
             self._run("trmm", tasks, mats, out.matrix_id, eph)
             return out
 
+    @_api_call
     def trsm(self, A: ArrayLike, B: ArrayLike, *, alpha: float = 1.0,
              side: str = "L", uplo: str = "U", transa: str = "N",
              diag: str = "N", tile: Optional[int] = None,
@@ -900,10 +939,11 @@ class BlasxContext:
             self._check_side_r_handles(dtype, A=A, B=B)
             # X*op(A) = alpha*B  ==  op(A)^T X^T = alpha B^T
             flip = "T" if transa.upper()[0] == "N" else "N"
-            out = self.trsm(_array_of(A),
-                            np.ascontiguousarray(_array_of(B).T),
-                            alpha=alpha, side="L", uplo=uplo, transa=flip,
-                            diag=diag, tile=tile, dtype=dtype)
+            with telemetry.span("blasx.prep"):
+                Bt = np.ascontiguousarray(_array_of(B).T)
+            out = self.trsm(_array_of(A), Bt, alpha=alpha, side="L",
+                            uplo=uplo, transa=flip, diag=diag, tile=tile,
+                            dtype=dtype)
             return self._transposed_result(out)
         dt = self._resolve_dtype(dtype)
         strict = dtype is not None
@@ -922,14 +962,16 @@ class BlasxContext:
                 Ah.array().dtype, Bh.array().dtype)
             self._check_exec_dtype(out_dt, Ah.dtype, Bh.dtype)
             out = self._fresh_out(m, n, Ah.tile, out_dt)
-            tasks = taskmod.taskize_trsm(Ah.tiled.grid, Bh.tiled.grid,
-                                         out.tiled.grid, uplo, transa,
-                                         diag, alpha)
+            with telemetry.span("blasx.plan"):
+                tasks = taskmod.taskize_trsm(Ah.tiled.grid, Bh.tiled.grid,
+                                             out.tiled.grid, uplo, transa,
+                                             diag, alpha)
             mats = {h.matrix_id: h.tiled for h in (Ah, Bh, out)}
             self._run("trsm", tasks, mats, out.matrix_id, eph)
             return out
 
     # --------------------------------------------------------- batched API
+    @_api_call
     def gemm_batched(self, As: Sequence[ArrayLike], Bs: Sequence[ArrayLike],
                      Cs: Optional[Sequence[ArrayLike]] = None, *,
                      alpha: float = 1.0, beta: float = 0.0,
@@ -942,6 +984,7 @@ class BlasxContext:
                             transa=transa, transb=transb, tile=tile,
                             dtype=dtype)
 
+    @_api_call
     def gemm_strided_batched(self, A, B, C=None, *, alpha: float = 1.0,
                              beta: float = 0.0, transa: str = "N",
                              transb: str = "N",
@@ -998,10 +1041,11 @@ class BlasxContext:
         registry — rejecting legacy exotic result dtypes (e.g. integer
         inputs promoted by the left-side call) that this epilogue must
         preserve as-is."""
-        arr = np.ascontiguousarray(out.array().T)
-        mid = f"M{next(_MATRIX_IDS)}"
-        res = MatrixHandle(self, TiledMatrix(mid, arr, out.tile))
-        out.invalidate()
+        with telemetry.span("blasx.prep"):
+            arr = np.ascontiguousarray(out.array().T)
+            mid = f"M{next(_MATRIX_IDS)}"
+            res = MatrixHandle(self, TiledMatrix(mid, arr, out.tile))
+            out.invalidate()
         return res
 
     def _prep_c(self, C: Optional[ArrayLike], shape, tile: int, dtype,

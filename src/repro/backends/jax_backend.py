@@ -34,6 +34,7 @@ from typing import Sequence
 
 import numpy as np
 
+from .. import telemetry
 from .base import ExecutionBackend, GroupResult, StepGroupKey
 
 
@@ -94,13 +95,32 @@ def stack_items(key: StepGroupKey, a_tiles: Sequence[np.ndarray],
             b.reshape(g, key.steps, key.k, key.n))
 
 
+def run_staged(fn, key: StepGroupKey, a_tiles: Sequence[np.ndarray],
+               b_tiles: Sequence[np.ndarray], engine: str) -> GroupResult:
+    """One group on the device: stage its tiles on the host, hand them to
+    the device with a ``device_put`` waited on (so ``blasx.h2d`` ends
+    where ``blasx.kernel`` starts), run the jitted ``fn(a, b)`` and fetch
+    its products in the group's dtype."""
+    import jax
+
+    with telemetry.span("blasx.stage"):
+        a, b = stack_items(key, a_tiles, b_tiles)
+    with telemetry.span("blasx.h2d"):
+        telemetry.count("h2d_bytes", a.nbytes + b.nbytes)
+        a, b = jax.block_until_ready(jax.device_put((a, b)))
+    with telemetry.span("blasx.kernel"):
+        out = fn(a, b).block_until_ready()
+    with telemetry.span("blasx.d2h"):
+        out = np.asarray(out)
+        if out.dtype != np.dtype(key.dtype):
+            out = out.astype(key.dtype)
+    return GroupResult(list(out), launches=1, engine=engine)
+
+
 class JaxBackend(ExecutionBackend):
     name = "jax"
 
     def run_group(self, key: StepGroupKey, a_tiles: Sequence[np.ndarray],
                   b_tiles: Sequence[np.ndarray]) -> GroupResult:
-        a, b = stack_items(key, a_tiles, b_tiles)
-        out = np.asarray(_group_contract()(a, b))
-        if out.dtype != np.dtype(key.dtype):
-            out = out.astype(key.dtype)
-        return GroupResult(list(out), launches=1, engine=self.name)
+        return run_staged(_group_contract(), key, a_tiles, b_tiles,
+                          self.name)

@@ -30,7 +30,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 from .base import ExecutionBackend, GroupResult, StepGroupKey
-from .jax_backend import JaxBackend, stack_items
+from .jax_backend import JaxBackend, run_staged
 
 # ops whose full-fill steps are plain C += A @ B tile multiplies
 _PALLAS_OPS = ("gemm", "syrk", "syr2k", "symm")
@@ -85,8 +85,4 @@ class PallasBackend(ExecutionBackend):
                      else _use_interpret())
         fn = _batched_pallas_contract(key.steps, key.m, key.k, key.n,
                                       key.dtype, interpret)
-        a, b = stack_items(key, a_tiles, b_tiles)
-        out = np.asarray(fn(a, b))
-        if out.dtype != np.dtype(key.dtype):
-            out = out.astype(key.dtype)
-        return GroupResult(list(out), launches=1, engine=self.name)
+        return run_staged(fn, key, a_tiles, b_tiles, self.name)

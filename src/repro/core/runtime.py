@@ -28,6 +28,7 @@ Scheduling policies (the paper's baselines are implemented, §II):
 """
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import threading
 import time
@@ -35,6 +36,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import telemetry
 from ..backends import create_backend
 from ..backends.base import StepGroupKey
 from .alru import Alru
@@ -389,11 +391,26 @@ class BlasxRuntime:
         request's priority-class term, added to every task's Eq. 3
         locality priority for the duration of the run (the serving
         front end maps ``interactive``/``batch`` onto it)."""
-        self._tenant = tenant
-        self._boost = float(priority_boost)
-        self.runs += 1
-        if not tasks:
-            return
+        with telemetry.span("blasx.run"):
+            self._tenant = tenant
+            self._boost = float(priority_boost)
+            self.runs += 1
+            if not tasks:
+                return
+            with telemetry.span("blasx.plan"):
+                tasks = self._plan(tasks, matrices)
+            self._matrices = matrices
+            self._out_id = out_id
+            self._completed: Dict[int, float] = {}
+            if self.cfg.mode == "threads":
+                self._run_threads(tasks)
+            else:
+                self._run_sim(tasks)
+
+    def _plan(self, tasks: Sequence[Task],
+              matrices: Dict[str, TiledMatrix]) -> Sequence[Task]:
+        """The planners the config asks for, then the ready queue (or
+        the static split) the devices will drain; returns the tasks."""
         if self.cfg.work_centric:
             tasks = taskmod.plan_work_centric(
                 tasks, {mid: m.grid for mid, m in matrices.items()},
@@ -405,20 +422,13 @@ class BlasxRuntime:
             # tasks, so the two compose without double-splitting)
             tasks = taskmod.plan_panel_staged(tasks, matrices,
                                               self.cfg.cache_bytes)
-        self._matrices = matrices
-        self._out_id = out_id
         if self.cfg.static_assignment:
-            queues = self._static_split(tasks)
             self._queue = None
-            self._static_queues = queues
+            self._static_queues = self._static_split(tasks)
         else:
             self._queue = ReadyQueue(tasks)
             self._static_queues = None
-        self._completed: Dict[int, float] = {}
-        if self.cfg.mode == "threads":
-            self._run_threads(tasks)
-        else:
-            self._run_sim(tasks)
+        return tasks
 
     # ----------------------------------------------------- static policies
     def _static_split(self, tasks: Sequence[Task]) -> List[ReadyQueue]:
@@ -558,7 +568,10 @@ class BlasxRuntime:
                     errors.append(e)
                     cv.notify_all()
 
-        threads = [threading.Thread(target=worker, args=(d,), daemon=True)
+        # each worker runs in a copy of the caller's context: its spans
+        # carry the id of the API call it serves
+        threads = [threading.Thread(target=contextvars.copy_context().run,
+                                    args=(worker, d), daemon=True)
                    for d in self.devices]
         for th in threads:
             th.start()
@@ -674,24 +687,27 @@ class BlasxRuntime:
         compute_each: List[float] = []
         recs: List[_TaskExec] = []
         try:
-            for t in batch:
-                rec, secs = self._gather_task(d, t, acquired)
-                recs.append(rec)
-                comm_s += secs
+            with telemetry.span("blasx.gather"):
+                for t in batch:
+                    rec, secs = self._gather_task(d, t, acquired)
+                    recs.append(rec)
+                    comm_s += secs
             if self.cfg.execute:
-                self._dispatch_steps(d, recs)
-            for rec in recs:
-                comm_s += self._finalize_task(d, rec)
-                compute_each.append(
-                    rec.task.flops / (d.speed * self.cfg.device_peak_flops))
-                d.ledger.tasks += 1
-                d.ledger.flops += rec.task.flops
-                if rec.task.kind == KIND_PARTIAL:
-                    d.ledger.partial_tasks += 1
-                    d.ledger.partial_flops += rec.task.flops
-                elif rec.task.kind == KIND_FIXUP:
-                    d.ledger.fixup_tasks += 1
-                    d.ledger.fixup_flops += rec.task.flops
+                with telemetry.span("blasx.dispatch"):
+                    self._dispatch_steps(d, recs)
+            with telemetry.span("blasx.finalize"):
+                for rec in recs:
+                    comm_s += self._finalize_task(d, rec)
+                    compute_each.append(rec.task.flops / (
+                        d.speed * self.cfg.device_peak_flops))
+                    d.ledger.tasks += 1
+                    d.ledger.flops += rec.task.flops
+                    if rec.task.kind == KIND_PARTIAL:
+                        d.ledger.partial_tasks += 1
+                        d.ledger.partial_flops += rec.task.flops
+                    elif rec.task.kind == KIND_FIXUP:
+                        d.ledger.fixup_tasks += 1
+                        d.ledger.fixup_flops += rec.task.flops
         except BaseException:
             # a failing batch must not leave its acquired tiles pinned:
             # the readers would never hit the release below, permanently
@@ -703,21 +719,22 @@ class BlasxRuntime:
         # reader update (the ALRU may evict these from now on)
         for key in acquired:
             d.alru.release(key)
-        compute_s = sum(compute_each)
-        d.ledger.compute_time += compute_s
-        d.ledger.comm_time += comm_s
-        if self._engine is not None:
-            return self._schedule_events(d, recs, compute_each, compute_s,
-                                         comm_s, start)
-        # lump-sum model (time_model="lump" and threads mode): one
-        # duration for the whole batch, all tasks finish together
-        if self.cfg.overlap:
-            d.ledger.unoverlapped_comm += max(0.0, comm_s - compute_s)
-            dur = max(compute_s, comm_s)
-        else:
-            d.ledger.unoverlapped_comm += comm_s
-            dur = compute_s + comm_s
-        return dur, [start + dur] * len(batch)
+        with telemetry.span("blasx.model"):
+            compute_s = sum(compute_each)
+            d.ledger.compute_time += compute_s
+            d.ledger.comm_time += comm_s
+            if self._engine is not None:
+                return self._schedule_events(d, recs, compute_each,
+                                             compute_s, comm_s, start)
+            # lump-sum model (time_model="lump" and threads mode): one
+            # duration for the whole batch, all tasks finish together
+            if self.cfg.overlap:
+                d.ledger.unoverlapped_comm += max(0.0, comm_s - compute_s)
+                dur = max(compute_s, comm_s)
+            else:
+                d.ledger.unoverlapped_comm += comm_s
+                dur = compute_s + comm_s
+            return dur, [start + dur] * len(batch)
 
     def _schedule_events(self, d: DeviceSim, recs: List["_TaskExec"],
                          compute_each: List[float], compute_s: float,
@@ -842,9 +859,10 @@ class BlasxRuntime:
                     step_groups.setdefault(key, []).append((rec, i))
         led = d.ledger
         for key, t_recs in task_groups.items():
-            res = self.backend.run_group(
-                key, [a for r in t_recs for a in r.a_tiles],
-                [b for r in t_recs for b in r.b_tiles])
+            with self._group_span(key, len(t_recs)):
+                res = self.backend.run_group(
+                    key, [a for r in t_recs for a in r.a_tiles],
+                    [b for r in t_recs for b in r.b_tiles])
             n_steps = key.steps * len(t_recs)
             led.batched_groups += 1
             led.batched_steps += n_steps
@@ -855,9 +873,10 @@ class BlasxRuntime:
             for rec, acc in zip(t_recs, res.products):
                 rec.acc = acc
         for key, entries in step_groups.items():
-            res = self.backend.run_group(
-                key, [r.a_tiles[i] for r, i in entries],
-                [r.b_tiles[i] for r, i in entries])
+            with self._group_span(key, len(entries)):
+                res = self.backend.run_group(
+                    key, [r.a_tiles[i] for r, i in entries],
+                    [r.b_tiles[i] for r, i in entries])
             led.batched_groups += 1
             led.batched_steps += len(entries)
             led.kernel_launches += res.launches
@@ -866,6 +885,11 @@ class BlasxRuntime:
                 + key.flops_per_item * len(entries))
             for (rec, idx), prod in zip(entries, res.products):
                 rec.products[idx] = prod
+
+    @staticmethod
+    def _group_span(key: StepGroupKey, items: int):
+        return telemetry.span("blasx.group", op=key.op, m=key.m, k=key.k,
+                              n=key.n, steps=key.steps, items=items)
 
     def _finalize_task(self, d: DeviceSim, rec: "_TaskExec") -> float:
         """Phase 3: per-task epilogue + write-back; returns comm secs."""
