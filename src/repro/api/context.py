@@ -849,9 +849,9 @@ class BlasxContext:
             self._check_side_r_handles(dtype, A=A, B=B)
             # C = alpha*B*A + beta*C  ==  (alpha*A*B^T + beta*C^T)^T
             with telemetry.span("blasx.prep"):
-                Bt = np.ascontiguousarray(_array_of(B).T)
+                Bt = _host_transpose(_array_of(B))
                 Ct = None if C is None else \
-                    np.ascontiguousarray(_as2d(_array_of(C), "C").T)
+                    _host_transpose(_as2d(_array_of(C), "C"))
             out = self.symm(_array_of(A), Bt, Ct, alpha=alpha, beta=beta,
                             side="L", uplo=uplo, tile=tile, dtype=dtype)
             return self._transposed_result(out)
@@ -894,7 +894,7 @@ class BlasxContext:
             # B*op(A) == (op(A)^T B^T)^T — §III-C at matrix granularity
             flip = "T" if transa.upper()[0] == "N" else "N"
             with telemetry.span("blasx.prep"):
-                Bt = np.ascontiguousarray(_array_of(B).T)
+                Bt = _host_transpose(_array_of(B))
             out = self.trmm(_array_of(A), Bt, alpha=alpha, side="L",
                             uplo=uplo, transa=flip, diag=diag, tile=tile,
                             dtype=dtype)
@@ -932,32 +932,32 @@ class BlasxContext:
              side: str = "L", uplo: str = "U", transa: str = "N",
              diag: str = "N", tile: Optional[int] = None,
              dtype=None) -> MatrixHandle:
-        """Solve op(tri(A)) @ X = alpha * B (side='L'; Eq. 1c); returns X."""
+        """Solve op(tri(A)) @ X = alpha * B (side='L'; Eq. 1c), or
+        X @ op(tri(A)) = alpha * B (side='R'); returns X.
+
+        Both sides run as one tile algorithm, mirrored
+        (:func:`~repro.core.task.taskize_trsm`).  ``side='R'`` checks
+        handles like ``side='L'``, then tiles their data afresh like raw
+        arrays, at the call's tile and dtype."""
         self._check_open()
         side = side.upper()[0]
         if side == "R":
             self._check_side_r_handles(dtype, A=A, B=B)
-            # X*op(A) = alpha*B  ==  op(A)^T X^T = alpha B^T
-            flip = "T" if transa.upper()[0] == "N" else "N"
-            with telemetry.span("blasx.prep"):
-                Bt = np.ascontiguousarray(_array_of(B).T)
-            out = self.trsm(_array_of(A), Bt, alpha=alpha, side="L",
-                            uplo=uplo, transa=flip, diag=diag, tile=tile,
-                            dtype=dtype)
-            return self._transposed_result(out)
+            A, B = _array_of(A), _array_of(B)
         dt = self._resolve_dtype(dtype)
         strict = dtype is not None
         with self._lock:
             b_sh = _shape_of(B)
-            tile = self._tile_arg(tile, "trsm", b_sh[0], b_sh[0], b_sh[1],
-                                  dt, (A, B))
+            # A is square on B's rows (side L) or on its columns (side R)
+            ka, kn = b_sh[::-1] if side == "R" else b_sh
+            tile = self._tile_arg(tile, "trsm", ka, ka, kn, dt, (A, B))
             eph: List[MatrixHandle] = []
             Ah = self._coerce(A, "A", tile, eph, dt, strict)
             Bh = self._coerce(B, "B", tile, eph, dt, strict)
             self._check_tiles(Ah, Bh)
             m, n = Bh.shape
-            if Ah.shape != (m, m):
-                raise ValueError(f"A must be ({m},{m}), got {Ah.shape}")
+            if Ah.shape != (ka, ka):
+                raise ValueError(f"A must be ({ka},{ka}), got {Ah.shape}")
             out_dt = dt if dt is not None else promote_dtypes(
                 Ah.array().dtype, Bh.array().dtype)
             self._check_exec_dtype(out_dt, Ah.dtype, Bh.dtype)
@@ -965,7 +965,7 @@ class BlasxContext:
             with telemetry.span("blasx.plan"):
                 tasks = taskmod.taskize_trsm(Ah.tiled.grid, Bh.tiled.grid,
                                              out.tiled.grid, uplo, transa,
-                                             diag, alpha)
+                                             diag, alpha, side=side)
             mats = {h.matrix_id: h.tiled for h in (Ah, Bh, out)}
             self._run("trsm", tasks, mats, out.matrix_id, eph)
             return out
@@ -998,12 +998,13 @@ class BlasxContext:
 
     # ------------------------------------------------------------- helpers
     def _check_side_r_handles(self, dtype, **operands) -> None:
-        """side='R' reductions degrade handles to raw transposed
-        copies; enforce the same ownership and dtype-mismatch rules
-        the side='L' coercion path applies, so both sides reject an
-        explicit ``dtype=`` that contradicts a handle's storage instead
-        of silently recasting.  Like side='L', the context default is
-        not enforced against handles — only a per-call override is."""
+        """side='R' calls degrade handles to raw arrays (transposed
+        copies in trmm and symm); enforce the same ownership and
+        dtype-mismatch rules the side='L' coercion path applies, so
+        both sides reject an explicit ``dtype=`` that contradicts a
+        handle's storage instead of silently recasting.  Like side='L',
+        the context default is not enforced against handles — only a
+        per-call override is."""
         dt = self._resolve_dtype(dtype) if dtype is not None else None
         for name, x in operands.items():
             if isinstance(x, MatrixHandle):
@@ -1042,7 +1043,7 @@ class BlasxContext:
         inputs promoted by the left-side call) that this epilogue must
         preserve as-is."""
         with telemetry.span("blasx.prep"):
-            arr = np.ascontiguousarray(out.array().T)
+            arr = _host_transpose(out.array())
             mid = f"M{next(_MATRIX_IDS)}"
             res = MatrixHandle(self, TiledMatrix(mid, arr, out.tile))
             out.invalidate()
@@ -1068,6 +1069,14 @@ class BlasxContext:
         # would otherwise put half-precision tiles through the engine.
         self._check_exec_dtype(c.dtype)
         return self._fresh_out(shape[0], shape[1], tile, c.dtype, seed=c)
+
+
+def _host_transpose(a: np.ndarray) -> np.ndarray:
+    """A whole operand's transpose, copied on the host; its bytes go to
+    the ``host_transpose_bytes`` counter of ``repro.telemetry``."""
+    t = np.ascontiguousarray(a.T)
+    telemetry.count("host_transpose_bytes", t.nbytes)
+    return t
 
 
 def _array_of(x: ArrayLike) -> np.ndarray:

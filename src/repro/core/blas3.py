@@ -8,10 +8,11 @@ runtime and its ALRU/MESI-X tile caches are built once per process —
 not per call.  ``config=`` runs a call on a fresh, private runtime;
 ``runtime=`` adopts an existing one (ledgers accumulate on it).
 
-``side='R'`` cases reduce to the native left-side tile algorithms via
-the transpose identities (op(A)^T X^T = alpha B^T), mirroring the
-paper's §III-C trick at matrix granularity — the reduction happens
-inside the context methods.
+``side='R'`` TRSM runs its own tile algorithm, the left side's
+mirrored; ``side='R'`` SYMM and TRMM reduce to the left-side tile
+algorithms via the transpose identities (B op(A) = (op(A)^T B^T)^T),
+the paper's §III-C trick at matrix granularity, inside the context
+methods.
 
 ``tile=`` accepts an int (default 256) or ``"auto"``: the latter
 resolves the tile size through the runtime autotuner

@@ -47,7 +47,8 @@ from .heap import BlasxHeap
 from . import task as taskmod
 from .task import KIND_FIXUP, KIND_PARTIAL, Ledger, Task, TileRef
 from .taskqueue import ReadyQueue, ReservationStation
-from .tile_kernels import materialize, solve_triangular
+from .tile_kernels import (materialize, solve_triangular,
+                           solve_triangular_right)
 from .tiling import TiledMatrix, TileKey
 
 # paper Table IV: measured DMA throughputs on Everest
@@ -911,7 +912,11 @@ class BlasxRuntime:
             if acc is None:
                 h, w = out_grid.grid.tile_shape(t.i, t.j)
                 acc = np.zeros((h, w), dtype=out_grid.data.dtype)
-            if t.finalize is not None:  # TRSM
+            if t.finalize is not None and t.finalize.side == "R":
+                result = solve_triangular_right(
+                    rec.diag, t.alpha * rec.rhs - acc,
+                    lower=t.finalize.lower, unit_diag=t.finalize.unit_diag)
+            elif t.finalize is not None:  # TRSM
                 result = solve_triangular(rec.diag, t.alpha * rec.rhs - acc,
                                           lower=t.finalize.lower,
                                           unit_diag=t.finalize.unit_diag)

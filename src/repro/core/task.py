@@ -119,13 +119,16 @@ class Step:
 
 @dataclasses.dataclass(frozen=True)
 class Finalize:
-    """TRSM finalize: ``C_ij = solve(tri(A_ii), alpha * B_ij - acc)``."""
+    """TRSM finalize: ``C_ij = solve(tri(A_ii), alpha * B_ij - acc)``
+    (side 'L'), or ``C_ij = (alpha * B_ij - acc) tri(A_jj)^-1`` (side
+    'R')."""
 
     kind: str            # 'trsm'
-    diag_ref: TileRef    # A_ii with triangular fill
+    diag_ref: TileRef    # op(A) diagonal tile with triangular fill
     rhs_ref: TileRef     # B_ij
-    lower: bool
+    lower: bool          # triangle of op(A)'s diagonal tile
     unit_diag: bool
+    side: str = "L"
 
 
 # work-centric (Stream-K) task kinds — see ``plan_work_centric``
@@ -205,8 +208,8 @@ class TaskBuilder:
             g = self.grids[fin.diag_ref.key.matrix_id]
             t, _ = g.tile_shape(fin.diag_ref.key.i, fin.diag_ref.key.j)
             gc = self.grids[kw["out"].matrix_id]
-            _, n = gc.tile_shape(kw["i"], kw["j"])
-            flops += t * t * n  # triangular solve
+            m, n = gc.tile_shape(kw["i"], kw["j"])
+            flops += t * t * (n if fin.side == "L" else m)  # triangular solve
         task = Task(task_id=self._next_id, flops=flops, **kw)
         self._next_id += 1
         self.tasks.append(task)
@@ -365,15 +368,22 @@ def taskize_trmm(ga: TileGrid, gcin: TileGrid, gc: TileGrid,
 # TRSM (Eq. 1c, side=L): solve op(A) X = alpha * B, X overwrites B.
 #   X_ij = tri(A_ii)^{-1} (alpha*B_ij - sum_{k after i} op(A)_ik X_kj)
 # Tasks within a column form a chain — expressed via ``deps``.
+# side=R mirrors it: solve X op(A) = alpha * B one tile column at a time,
+#   X_ij = (alpha*B_ij - sum_{k before j} X_ik op(A)_kj) tri(A_jj)^{-1}
+# ("before" is k < j for an effectively upper op(A), k > j for lower);
+# the chains run along the rows of X.
 # --------------------------------------------------------------------------
 def taskize_trsm(ga: TileGrid, gb: TileGrid, gc: TileGrid,
                  uplo: str, transa: str, diag: str,
-                 alpha: float) -> List[Task]:
+                 alpha: float, side: str = "L") -> List[Task]:
     uplo, transa, diag = uplo.upper()[0], transa.upper()[0], diag.upper()[0]
     b = TaskBuilder({g.matrix_id: g for g in (ga, gb, gc)})
-    z = gc.n_tile_rows - 1
     eff_upper = (uplo == "U") == (transa == "N")
     tri_fill = _tri_fill(uplo, diag)
+    if side.upper()[0] == "R":
+        return _taskize_trsm_right(b, ga, gb, gc, transa, diag, alpha,
+                                   eff_upper, tri_fill)
+    z = gc.n_tile_rows - 1
     order = range(z, -1, -1) if eff_upper else range(0, z + 1)
     # map (i, j) -> task id for dependency wiring
     tid = {}
@@ -391,6 +401,38 @@ def taskize_trsm(ga: TileGrid, gb: TileGrid, gc: TileGrid,
                 rhs_ref=TileRef(gb.key(i, j)),
                 lower=not eff_upper,
                 unit_diag=(diag == "U"),
+            )
+            t = b.add(routine="trsm", out=gc.key(i, j), i=i, j=j,
+                      steps=tuple(steps), alpha=alpha, beta=0.0,
+                      finalize=fin, deps=tuple(deps))
+            tid[(i, j)] = t.task_id
+    return b.tasks
+
+
+def _taskize_trsm_right(b: TaskBuilder, ga: TileGrid, gb: TileGrid,
+                        gc: TileGrid, transa: str, diag: str, alpha: float,
+                        eff_upper: bool, tri_fill: str) -> List[Task]:
+    z = gc.n_tile_cols - 1
+    order = range(0, z + 1) if eff_upper else range(z, -1, -1)
+    tid = {}
+    for i in range(gc.n_tile_rows):
+        for j in order:
+            ks = range(0, j) if eff_upper else range(j + 1, z + 1)
+            steps = []
+            deps = []
+            for k in ks:
+                steps.append(Step(TileRef(gc.key(i, k)),
+                                  _op_a(ga, transa, k, j)))
+                deps.append(tid[(i, k)])
+            fin = Finalize(
+                kind="trsm",
+                # for transa T a transposed view of the stored tile: the
+                # solve reads it in place (tile_kernels.solve_triangular_right)
+                diag_ref=_op_a(ga, transa, j, j, fill=tri_fill),
+                rhs_ref=TileRef(gb.key(i, j)),
+                lower=not eff_upper,
+                unit_diag=(diag == "U"),
+                side="R",
             )
             t = b.add(routine="trsm", out=gc.key(i, j), i=i, j=j,
                       steps=tuple(steps), alpha=alpha, beta=0.0,

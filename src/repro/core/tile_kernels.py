@@ -53,3 +53,15 @@ def solve_triangular(a: np.ndarray, b: np.ndarray, lower: bool,
     """Tile-level triangular solve for the TRSM finalize step."""
     return scipy.linalg.solve_triangular(
         a, b, lower=lower, unit_diagonal=unit_diag, check_finite=False)
+
+
+def solve_triangular_right(a: np.ndarray, r: np.ndarray, lower: bool,
+                           unit_diag: bool) -> np.ndarray:
+    """Side-R TRSM finalize: X with ``X @ a = r``, solved as
+    ``a^T X^T = r^T`` by the solver's own ``trans`` flag.  ``r.T`` is
+    Fortran-ordered, so LAPACK solves in ``r``'s buffer (``r`` is
+    overwritten) with no re-layout, and the result's ``.T`` is C-ordered
+    again for the write-back."""
+    return scipy.linalg.solve_triangular(
+        a, r.T, trans="T", lower=lower, unit_diagonal=unit_diag,
+        overwrite_b=True, check_finite=False).T

@@ -182,6 +182,73 @@ def test_context_trmm_trsm_sides(side, transa):
         rtol=1e-8, atol=1e-8)
 
 
+# ------------------------------------------------ side-R TRSM, run natively
+# B's (m, n) at tile 16: A one tile (a blocked Cholesky's panel solve,
+# B of several tile rows); A of 3x3 tiles (k-chains along X's rows); m
+# and n both ragged
+SIDE_R_SHAPES = {"one_tile": (40, 16), "chains": (32, 48),
+                 "ragged": (27, 40)}
+F32_TOL = dict(rtol=2e-3, atol=2e-3)   # the precision suite's f32 limit
+
+
+def _side_r_operands(shape):
+    m, n = SIDE_R_SHAPES[shape]
+    # small off-diagonal, dominant diagonal: well conditioned for diag U too
+    A = RNG.standard_normal((n, n)) / n + np.eye(n)
+    return A, RNG.standard_normal((m, n))
+
+
+@pytest.mark.parametrize("backend,hold", [("numpy", "raw"),
+                                          ("numpy", "handles"),
+                                          ("jax", "raw"),
+                                          ("pallas", "handles")])
+@pytest.mark.parametrize("shape", sorted(SIDE_R_SHAPES))
+@pytest.mark.parametrize("alpha", [1.0, -0.5])
+@pytest.mark.parametrize("diag", ["N", "U"])
+@pytest.mark.parametrize("transa", ["N", "T"])
+@pytest.mark.parametrize("uplo", ["U", "L"])
+def test_trsm_side_r_native(uplo, transa, diag, alpha, shape, backend, hold):
+    A, B = _side_r_operands(shape)
+    kw = dict(alpha=alpha, uplo=uplo, diag=diag)
+    want = ref_trsm(A, B, side="R", transa=transa, **kw)
+    with BlasxContext(RuntimeConfig(n_devices=1, mode="sim",
+                                    backend=backend), tile=16) as ctx:
+        ops = (A, B) if hold == "raw" else (ctx.tile(A), ctx.tile(B))
+        out = ctx.trsm(*ops, side="R", transa=transa, **kw)
+        assert ctx.n_calls == 1
+        assert out.shape == B.shape
+        np.testing.assert_allclose(
+            out.array(), want,
+            **(dict(rtol=1e-8, atol=1e-8) if backend == "numpy"
+               else F32_TOL))
+        if backend == "numpy" and shape == "one_tile":
+            # the former route: a left-side solve of the transposed copy
+            flip = "T" if transa == "N" else "N"
+            old = ctx.trsm(A, np.ascontiguousarray(B.T), side="L",
+                           transa=flip, **kw).array().T
+            np.testing.assert_array_equal(out.array(), old)
+
+
+@pytest.mark.parametrize("mode", ["threads", "sim"])
+def test_trsm_side_r_native_on_two_devices(mode):
+    """Two devices (threads, or sim, whose schedule spreads X's row
+    chains over both) agree with sim on one."""
+    A, B = _side_r_operands("ragged")
+    B = np.vstack([B, RNG.standard_normal((70, B.shape[1]))])
+    kw = dict(alpha=-0.5, side="R", uplo="U", transa="T")
+    with BlasxContext(RuntimeConfig(n_devices=1, mode="sim"),
+                      tile=16) as one:
+        want = one.trsm(A, B, **kw).array()
+    with BlasxContext(RuntimeConfig(n_devices=2, mode=mode),
+                      tile=16) as two:
+        got = two.trsm(A, B, **kw).array()
+        if mode == "sim":   # a threads worker may take every task
+            assert all(d.ledger.tasks for d in two.runtime.devices)
+    np.testing.assert_allclose(got, want, rtol=1e-12, atol=1e-12)
+    np.testing.assert_allclose(got, ref_trsm(A, B, **kw), rtol=1e-8,
+                               atol=1e-8)
+
+
 @pytest.mark.parametrize("routine", ["gemm", "syrk", "syr2k", "symm"])
 def test_beta_accumulation_matches_oracle(routine):
     """beta != 0 reads C through the ledgered bypass path — verify the

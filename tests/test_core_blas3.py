@@ -168,6 +168,79 @@ def test_trsm(side, uplo, transa, diag):
     np.testing.assert_allclose(out, ref, rtol=1e-8, atol=1e-8)
 
 
+def _trsm_left_tasks_as_before(ga, gb, gc, uplo, transa, diag, alpha):
+    """The side-L TRSM taskizer as it stood before side R ran natively."""
+    from repro.core.task import (FILL_TRI_L, FILL_TRI_LU, FILL_TRI_U,
+                                 FILL_TRI_UU, Finalize, Step, TaskBuilder,
+                                 TileRef)
+
+    def op_a(i, k, fill="full"):
+        if transa == "N":
+            return TileRef(ga.key(i, k), fill=fill)
+        return TileRef(ga.key(k, i), trans=True, fill=fill)
+
+    b = TaskBuilder({g.matrix_id: g for g in (ga, gb, gc)})
+    z = gc.n_tile_rows - 1
+    eff_upper = (uplo == "U") == (transa == "N")
+    tri_fill = {("U", "N"): FILL_TRI_U, ("U", "U"): FILL_TRI_UU,
+                ("L", "N"): FILL_TRI_L, ("L", "U"): FILL_TRI_LU}[uplo, diag]
+    order = range(z, -1, -1) if eff_upper else range(0, z + 1)
+    tid = {}
+    for j in range(gc.n_tile_cols):
+        for i in order:
+            ks = range(i + 1, z + 1) if eff_upper else range(0, i)
+            steps = tuple(Step(op_a(i, k), TileRef(gc.key(k, j))) for k in ks)
+            fin = Finalize(kind="trsm", diag_ref=op_a(i, i, fill=tri_fill),
+                           rhs_ref=TileRef(gb.key(i, j)),
+                           lower=not eff_upper, unit_diag=(diag == "U"))
+            t = b.add(routine="trsm", out=gc.key(i, j), i=i, j=j,
+                      steps=steps, alpha=alpha, beta=0.0, finalize=fin,
+                      deps=tuple(tid[(k, j)] for k in ks))
+            tid[(i, j)] = t.task_id
+    return b.tasks
+
+
+@pytest.mark.parametrize("uplo", ["U", "L"])
+@pytest.mark.parametrize("transa", ["N", "T"])
+@pytest.mark.parametrize("diag", ["N", "U"])
+def test_trsm_side_l_task_list_unchanged(uplo, transa, diag):
+    """Side R's own taskization leaves side L's steps, refs, deps and
+    finalize exactly as they were (ragged 3x2 tile grid of B)."""
+    from repro.core.task import taskize_trsm
+    from repro.core.tiling import TileGrid
+
+    ga, gb, gc = (TileGrid("A", 40, 40, 16), TileGrid("B", 40, 27, 16),
+                  TileGrid("C", 40, 27, 16))
+    want = _trsm_left_tasks_as_before(ga, gb, gc, uplo, transa, diag, 0.7)
+    assert len(want) == 6 and any(t.deps for t in want)
+    assert taskize_trsm(ga, gb, gc, uplo, transa, diag, 0.7) == want
+    assert taskize_trsm(ga, gb, gc, uplo, transa, diag, 0.7,
+                        side="L") == want
+
+
+@pytest.mark.parametrize("uplo", ["U", "L"])
+@pytest.mark.parametrize("transa", ["N", "T"])
+def test_trsm_side_r_tasks_mirror_side_l(uplo, transa):
+    """Side R on B (27x40) is side L on B^T mirrored: as many tasks,
+    steps, dependencies and flops; its chains run along X's rows."""
+    from repro.core.task import taskize_trsm, total_flops
+    from repro.core.tiling import TileGrid
+
+    ga = TileGrid("A", 40, 40, 16)
+    right = taskize_trsm(ga, TileGrid("B", 27, 40, 16),
+                         TileGrid("C", 27, 40, 16), uplo, transa, "N", 1.0,
+                         side="R")
+    left = taskize_trsm(ga, TileGrid("B", 40, 27, 16),
+                        TileGrid("C", 40, 27, 16), uplo,
+                        "T" if transa == "N" else "N", "N", 1.0)
+    assert total_flops(right) == total_flops(left)
+    for f in (lambda t: len(t.steps), lambda t: len(t.deps)):
+        assert sorted(map(f, right)) == sorted(map(f, left))
+    by_id = {t.task_id: t for t in right}
+    assert all(by_id[d].i == t.i for t in right for d in t.deps)
+    assert all(t.finalize.side == "R" for t in right)
+
+
 def test_trsm_residual():
     """A @ X == alpha * B (solve property, independent of the oracle)."""
     m, n = 96, 40

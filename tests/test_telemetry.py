@@ -172,8 +172,8 @@ def test_the_call_path_opens_every_span_and_self_times_add_up(tracer):
     spans = dict(snap["spans"])
     outer = spans.pop("test.calls")
     assert set(spans) == PATH_SPANS
-    # the side-R trsm's left-side call nests in it
-    assert spans["blasx.call"]["count"] == 4
+    # one each: the side-R trsm runs as one call
+    assert spans["blasx.call"]["count"] == 3
     total = sum(s["self_s"] for s in snap["spans"].values())
     assert total == pytest.approx(wall, rel=0.01)
     # the library's spans cover its calls: little is left between them
@@ -181,6 +181,29 @@ def test_the_call_path_opens_every_span_and_self_times_add_up(tracer):
     # syrk: 10 lower tiles of a 4x4 grid, one 4-step item each, 4 tiles
     # of 32x32 float32 per operand; gemm: 16 items of 4 steps
     assert snap["counters"] == {"h2d_bytes": (16 + 10) * 2 * 4 * 32 * 32 * 4}
+
+
+def test_host_transposes_are_counted(tracer):
+    """``host_transpose_bytes``: a side-R trsm transposes nothing; a
+    side-R trmm and symm count each operand they transpose and the
+    transposed result."""
+    rng = np.random.default_rng(5)
+    A = rng.standard_normal((48, 48)) / 48 + np.eye(48)
+    B = rng.standard_normal((40, 48)).astype(np.float32)
+    C = rng.standard_normal((40, 48))
+    ctx = BlasxContext(RuntimeConfig(n_devices=1), tile=32)
+    tracer.enable()
+    ctx.trsm(A, B, side="R", uplo="L", transa="T")
+    assert "host_transpose_bytes" not in tracer.snapshot()["counters"]
+    out = ctx.trmm(A, B, side="R")
+    assert out.array().dtype == np.float32
+    assert tracer.snapshot()["counters"]["host_transpose_bytes"] == \
+        B.nbytes + out.array().nbytes
+    tracer.reset()
+    out = ctx.symm(A, B, C, beta=0.5, side="R")
+    assert tracer.snapshot()["counters"]["host_transpose_bytes"] == \
+        B.nbytes + C.nbytes + out.array().nbytes
+    ctx.close()
 
 
 def _profiling(monkeypatch):
