@@ -20,8 +20,11 @@ One process, in order:
 Error is normwise, ``max|C - R| / max|R|``: float32 must reach 1e-5
 and bfloat16 output 1e-2.  The times printed are smoke timings — wall
 seconds of one cold call (compile included) and one warm call — not a
-benchmark.  ``--four-chips`` runs only the distributed ring and GSPMD
-GEMM on a 2x2 mesh of four chips against a one-chip reference.
+benchmark.  ``--four-chips`` runs only what needs the four chips of a
+2x2 host: the distributed ring and GSPMD GEMM against a one-chip
+reference, and a 4096^2 bfloat16 ``ctx.gemm`` over four runtime
+devices, one per chip (threads mode), each of which must run step
+groups on its own chip.
 
 Without a TPU the script exits non-zero; nothing falls back to the CPU.
 JAX keeps its compile cache in ``JAX_COMPILATION_CACHE_DIR`` when that
@@ -47,6 +50,7 @@ GEMM_N, TILE = 8192, 1024
 CHAIN_N = SERVE_N = 4096
 SERVE_REQUESTS = 8
 FOUR_CHIP_N = 16384
+FOUR_DEVICE_N = 4096
 # normwise error limits: float32 accuracy, and one bfloat16 rounding of
 # the output (a single bf16 pass over f32 inputs lands near 1e-3)
 TOL = {"float32": 1e-5, "bfloat16": 1e-2}
@@ -60,7 +64,8 @@ def parse_args(argv=None) -> argparse.Namespace:
                     help="seed of the random inputs (default 0)")
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the distributed GEMM on a 2x2 mesh of "
-                         "four chips")
+                         "four chips and a GEMM over four runtime devices, "
+                         "one per chip")
     return ap.parse_args(argv)
 
 
@@ -252,6 +257,44 @@ def phase_four_chips(devices, n: int, seed: int) -> dict:
     return _report(result)
 
 
+def phase_four_devices(n: int, tile: int, seed: int) -> dict:
+    """``ctx.gemm`` in bfloat16 over four runtime devices in threads
+    mode, Pallas backend: every chip must run k-steps (the library's
+    ``chip_steps.<i>`` counters) and the result must match float64."""
+    from repro import telemetry
+    from repro.api import BlasxContext
+    from repro.core.runtime import RuntimeConfig
+
+    dtype = "bfloat16"
+    rng = np.random.default_rng(seed)
+    a, b = _inputs(rng, n, dtype), _inputs(rng, n, dtype)
+    ref = _f64(a) @ _f64(b)
+    cfg = RuntimeConfig(n_devices=4, mode="threads", backend="pallas")
+    telemetry.reset()
+    telemetry.enable()
+    try:
+        with BlasxContext(cfg, tile=tile) as ctx:
+            cold_c, cold = _timed(lambda: ctx.gemm(a, b, dtype=dtype).array())
+            warm_c, warm = _timed(lambda: ctx.gemm(a, b, dtype=dtype).array())
+            chips = [s["chip"] for s in ctx.stats()["devices"].values()]
+            launch = _launch(ctx, "four devices")
+    finally:
+        telemetry.disable()
+    steps = {k: v for k, v in telemetry.snapshot()["counters"].items()
+             if k.startswith("chip_steps.")}
+    telemetry.reset()
+    if chips != [0, 1, 2, 3]:
+        raise AssertionError(f"four devices: bound to chips {chips}")
+    if sorted(steps) != [f"chip_steps.{i}" for i in range(4)] \
+            or min(steps.values()) == 0:
+        raise AssertionError(f"four devices: a chip ran nothing: {steps}")
+    err = max(normwise_error(cold_c, ref), normwise_error(warm_c, ref))
+    _check(f"gemm over four devices {dtype} n={n}", err, dtype)
+    return _report({"phase": "four_devices", "dtype": dtype, "n": n,
+                    "err": err, "chip_steps": steps, "smoke_cold_s": cold,
+                    "smoke_warm_s": warm, "launch": launch})
+
+
 def pallas_lowers_to_mosaic(tile: int, steps: int, dtype: str) -> bool:
     """Compile the Pallas step-group kernel for the default device and
     report whether it became a Mosaic custom call (interpret mode would
@@ -295,6 +338,7 @@ def main(argv=None) -> int:
 
     if args.four_chips:
         phase_four_chips(devices, FOUR_CHIP_N, args.seed)
+        phase_four_devices(FOUR_DEVICE_N, TILE, args.seed)
     else:
         if not pallas_lowers_to_mosaic(TILE, GEMM_N // TILE, "float32"):
             raise AssertionError("the Pallas group kernel did not lower "

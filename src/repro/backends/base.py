@@ -25,7 +25,13 @@ Contract
   :class:`StepGroupKey`), in order, as numpy arrays (the runtime's
   cache/ledger layer is host-centric).
 * Backends must be callable from several device worker threads at once
-  (``mode="threads"``); compile caches are the only allowed state.
+  (``mode="threads"``); compile caches are the only allowed state, and
+  each is built once (:func:`build_once`).
+* A group runs on the chip the runtime placed its caller on
+  (:func:`on_chip`, read back by :func:`placed_chip`): the runtime's
+  device ``d`` is bound to one of the host's chips and every group it
+  dispatches goes there.  Outside such a placement a group runs on
+  JAX's default device.
 * ``launches`` in the returned :class:`GroupResult` is the number of
   kernel dispatches the group cost — the ledger currency behind the
   ``launches saved`` statistic.
@@ -33,10 +39,57 @@ Contract
 from __future__ import annotations
 
 import abc
+import contextlib
+import contextvars
 import dataclasses
-from typing import List, Sequence
+import functools
+import threading
+from typing import List, Optional, Sequence
 
 import numpy as np
+
+
+# index into ``jax.local_devices()`` of the chip the current step group
+# goes to; each threads-mode worker runs in a context of its own
+_CHIP: contextvars.ContextVar = contextvars.ContextVar("blasx_chip",
+                                                       default=None)
+
+
+@contextlib.contextmanager
+def on_chip(index: Optional[int]):
+    """Step groups dispatched inside run on ``jax.local_devices()[index]``
+    (None: JAX's default device)."""
+    token = _CHIP.set(index)
+    try:
+        yield
+    finally:
+        _CHIP.reset(token)
+
+
+def placed_chip():
+    """The JAX device the current step group goes to, or None for the
+    default device."""
+    index = _CHIP.get()
+    if index is None:
+        return None
+    import jax
+
+    return jax.local_devices()[index]
+
+
+def build_once(factory):
+    """``functools.lru_cache`` for kernel factories whose first call per
+    key makes one kernel even when several device workers ask at once
+    (a second kernel would compile every shape again)."""
+    cached = functools.lru_cache(maxsize=None)(factory)
+    lock = threading.Lock()
+
+    @functools.wraps(factory)
+    def get(*args):
+        with lock:
+            return cached(*args)
+
+    return get
 
 
 @dataclasses.dataclass(frozen=True)

@@ -29,16 +29,17 @@ pins float32 inputs.
 """
 from __future__ import annotations
 
-import functools
+import threading
 from typing import Sequence
 
 import numpy as np
 
 from .. import telemetry
-from .base import ExecutionBackend, GroupResult, StepGroupKey
+from .base import (ExecutionBackend, GroupResult, StepGroupKey, build_once,
+                   placed_chip)
 
 
-@functools.lru_cache(maxsize=None)
+@build_once
 def _group_contract():
     """Lazily import jax and build the jitted group kernel (one function;
     jit's own cache specializes it per shape/dtype)."""
@@ -95,19 +96,44 @@ def stack_items(key: StepGroupKey, a_tiles: Sequence[np.ndarray],
             b.reshape(g, key.steps, key.k, key.n))
 
 
+# (kernel, item shapes, dtype, chip, items) of every smaller group
+# compiled ahead of its first use
+_SMALLER: set = set()
+_SMALLER_LOCK = threading.Lock()
+
+
+def _compile_smaller_groups(fn, a, b, chip) -> None:
+    """Run ``fn`` once on every group of fewer items of this shape, the
+    first time a placed chip sees the shape: a threads-mode call ends
+    with smaller groups on whichever chips finish last or steal, and a
+    compile there would stall that chip in the middle of a later call."""
+    sig = (fn, a.shape[1:], b.shape[1:], str(a.dtype), chip.id)
+    with _SMALLER_LOCK:
+        todo = [g for g in range(1, a.shape[0]) if (sig, g) not in _SMALLER]
+        _SMALLER.update((sig, g) for g in todo)
+    for g in todo:
+        fn(a[:g], b[:g]).block_until_ready()
+
+
 def run_staged(fn, key: StepGroupKey, a_tiles: Sequence[np.ndarray],
                b_tiles: Sequence[np.ndarray], engine: str) -> GroupResult:
-    """One group on the device: stage its tiles on the host, hand them to
-    the device with a ``device_put`` waited on (so ``blasx.h2d`` ends
-    where ``blasx.kernel`` starts), run the jitted ``fn(a, b)`` and fetch
-    its products in the group's dtype."""
+    """One group on its chip: stage its tiles on the host, hand them to
+    the chip the runtime placed this worker on (:func:`placed_chip`) with
+    a ``device_put`` waited on (so ``blasx.h2d`` ends where
+    ``blasx.kernel`` starts), run the jitted ``fn(a, b)`` there, where
+    its operands are, and fetch its products in the group's dtype.  On a
+    placed chip, the first group of a shape also compiles its smaller
+    groups (:func:`_compile_smaller_groups`)."""
     import jax
 
+    chip = placed_chip()
     with telemetry.span("blasx.stage"):
         a, b = stack_items(key, a_tiles, b_tiles)
     with telemetry.span("blasx.h2d"):
         telemetry.count("h2d_bytes", a.nbytes + b.nbytes)
-        a, b = jax.block_until_ready(jax.device_put((a, b)))
+        a, b = jax.block_until_ready(jax.device_put((a, b), chip))
+    if chip is not None:
+        _compile_smaller_groups(fn, a, b, chip)
     with telemetry.span("blasx.kernel"):
         out = fn(a, b).block_until_ready()
     with telemetry.span("blasx.d2h"):

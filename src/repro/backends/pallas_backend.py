@@ -29,7 +29,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
-from .base import ExecutionBackend, GroupResult, StepGroupKey
+from .base import ExecutionBackend, GroupResult, StepGroupKey, build_once
 from .jax_backend import JaxBackend, run_staged
 
 # ops whose full-fill steps are plain C += A @ B tile multiplies
@@ -43,7 +43,7 @@ def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-@functools.lru_cache(maxsize=None)
+@build_once
 def _batched_pallas_contract(steps: int, m: int, k: int, n: int,
                              dtype: str, interpret: bool):
     """Shape-keyed compile cache: one jitted vmapped Pallas matmul per

@@ -38,7 +38,7 @@ import numpy as np
 
 from .. import telemetry
 from ..backends import create_backend
-from ..backends.base import StepGroupKey
+from ..backends.base import StepGroupKey, on_chip
 from .alru import Alru
 from .coherence import MesixDirectory
 from .dtypes import promote_dtypes
@@ -294,6 +294,20 @@ class RuntimeConfig:
         }
 
 
+def bind_chips(n_devices: int, backend: str) -> List[Optional[int]]:
+    """The chip each runtime device's step groups run on, as an index
+    into ``jax.local_devices()``: device ``d`` on chip ``d`` where the
+    host has ``n_devices`` chips or more, else on chip ``d mod count``
+    (one chip, or the CPU, takes every device).  The host backend
+    (``numpy``) runs on no chip: all None."""
+    if backend == "numpy":
+        return [None] * n_devices
+    import jax
+
+    count = len(jax.local_devices())
+    return [d % count for d in range(n_devices)]
+
+
 class DeviceSim:
     """One simulated accelerator: private heap + ALRU (L1 tile cache) +
     tile store (the actual bytes) + ledger."""
@@ -363,6 +377,10 @@ class BlasxRuntime:
         self.devices = [DeviceSim(d, cfg, self.directory)
                         for d in range(cfg.n_devices)]
         self.backend = create_backend(cfg.backend)
+        # device d -> its chip.  One device places nothing: its groups
+        # run on JAX's default device, chip 0, as they always have
+        self.chips = bind_chips(cfg.n_devices, cfg.backend)
+        self._placed = cfg.n_devices > 1 and self.chips[0] is not None
         self.runs = 0
         # serving front-end state (repro.serve): which tenant the
         # in-flight run belongs to (tags ALRU blocks for the quota
@@ -400,6 +418,10 @@ class BlasxRuntime:
                 return
             with telemetry.span("blasx.plan"):
                 tasks = self._plan(tasks, matrices)
+            if self._placed:
+                # a bound chip that runs nothing reads 0, not absent
+                for chip in sorted(set(self.chips)):
+                    telemetry.count(f"chip_steps.{chip}", 0)
             self._matrices = matrices
             self._out_id = out_id
             self._completed: Dict[int, float] = {}
@@ -544,7 +566,8 @@ class BlasxRuntime:
                             if done():
                                 return
                             if gen[0] == my_gen:
-                                cv.wait(timeout=0.05)
+                                with telemetry.span("blasx.wait"):
+                                    cv.wait(timeout=0.05)
                         continue
                     inflight[d.id] = batch
                     t0 = time.perf_counter()
@@ -694,7 +717,8 @@ class BlasxRuntime:
                     recs.append(rec)
                     comm_s += secs
             if self.cfg.execute:
-                with telemetry.span("blasx.dispatch"):
+                with telemetry.span("blasx.dispatch"), \
+                        on_chip(self._chip(d)):
                     self._dispatch_steps(d, recs)
             with telemetry.span("blasx.finalize"):
                 for rec in recs:
@@ -859,8 +883,10 @@ class BlasxRuntime:
                 for i, key in enumerate(keys):
                     step_groups.setdefault(key, []).append((rec, i))
         led = d.ledger
+        chip = self._chip(d)
+        steps_before = led.batched_steps
         for key, t_recs in task_groups.items():
-            with self._group_span(key, len(t_recs)):
+            with self._group_span(key, len(t_recs), chip):
                 res = self.backend.run_group(
                     key, [a for r in t_recs for a in r.a_tiles],
                     [b for r in t_recs for b in r.b_tiles])
@@ -874,7 +900,7 @@ class BlasxRuntime:
             for rec, acc in zip(t_recs, res.products):
                 rec.acc = acc
         for key, entries in step_groups.items():
-            with self._group_span(key, len(entries)):
+            with self._group_span(key, len(entries), chip):
                 res = self.backend.run_group(
                     key, [r.a_tiles[i] for r, i in entries],
                     [r.b_tiles[i] for r, i in entries])
@@ -886,11 +912,20 @@ class BlasxRuntime:
                 + key.flops_per_item * len(entries))
             for (rec, idx), prod in zip(entries, res.products):
                 rec.products[idx] = prod
+        if chip is not None:
+            telemetry.count(f"chip_steps.{chip}",
+                            led.batched_steps - steps_before)
+
+    def _chip(self, d: DeviceSim) -> Optional[int]:
+        """The chip device ``d``'s groups are placed on; None where the
+        runtime places nothing (one device, or the host backend)."""
+        return self.chips[d.id] if self._placed else None
 
     @staticmethod
-    def _group_span(key: StepGroupKey, items: int):
+    def _group_span(key: StepGroupKey, items: int, chip: Optional[int]):
+        meta = {} if chip is None else {"chip": chip}
         return telemetry.span("blasx.group", op=key.op, m=key.m, k=key.k,
-                              n=key.n, steps=key.steps, items=items)
+                              n=key.n, steps=key.steps, items=items, **meta)
 
     def _finalize_task(self, d: DeviceSim, rec: "_TaskExec") -> float:
         """Phase 3: per-task epilogue + write-back; returns comm secs."""
@@ -1119,7 +1154,8 @@ class BlasxRuntime:
                        evictions=d.alru.evictions,
                        quota_evictions=d.alru.quota_evictions,
                        cache_used=d.heap.used, clock=d.clock,
-                       overlap_efficiency=d.ledger.overlap_efficiency)
+                       overlap_efficiency=d.ledger.overlap_efficiency,
+                       chip=self.chips[d.id])
             out[f"device{d.id}"] = led
         return out
 

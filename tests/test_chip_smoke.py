@@ -71,7 +71,10 @@ def test_help_exits_before_jax_loads():
 
 def test_four_chip_phase_on_a_cpu_mesh():
     """The --four-chips control flow on a 2x2 mesh of host devices (jax
-    fixes the device count at start-up, hence the subprocess)."""
+    fixes the device count at start-up, hence the subprocess): the
+    distributed GEMM, and a GEMM over four runtime devices, one per
+    device (Pallas in interpret mode, slow enough that every worker
+    finds work)."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4 "
                         + env.get("XLA_FLAGS", "")).strip()
@@ -79,13 +82,21 @@ def test_four_chip_phase_on_a_cpu_mesh():
     code = textwrap.dedent("""
         import jax, chip_smoke
         chip_smoke.phase_four_chips(jax.devices(), 256, seed=0)
+        chip_smoke.phase_four_devices(512, 128, seed=0)
     """)
     proc = subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
                           capture_output=True, text=True, timeout=300)
     assert proc.returncode == 0, f"{proc.stdout}\n{proc.stderr}"
-    line = [ln for ln in proc.stdout.splitlines() if ln.startswith("phase ")]
-    result = json.loads(line[-1][len("phase "):])
+    lines = [json.loads(ln[len("phase "):])
+             for ln in proc.stdout.splitlines() if ln.startswith("phase ")]
+    result, devices = lines[-2], lines[-1]
     for mode in ("ring", "gspmd"):
         assert result[mode]["devices"] == 4
         assert result[mode]["err"] <= chip_smoke.TOL["bfloat16"]
     assert result["ring"]["collective_permutes"] > 0
+    assert devices["phase"] == "four_devices"
+    assert devices["err"] <= chip_smoke.TOL["bfloat16"]
+    assert sorted(devices["chip_steps"]) == [f"chip_steps.{i}"
+                                             for i in range(4)]
+    assert min(devices["chip_steps"].values()) > 0
+    assert devices["launch"]["engine_flops"] == {"pallas": 2 * 2 * 512 ** 3}

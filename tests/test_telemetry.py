@@ -311,3 +311,75 @@ def test_a_profiler_session_turns_the_tracer_on(tracer, tmp_path):
                  str(path)).planes for line in p.lines for e in line.events}
     assert {"blasx.call", "blasx.stage", "blasx.h2d", "blasx.kernel",
             "blasx.d2h", "blasx.finalize"} <= names
+
+
+def _annotations(monkeypatch, run):
+    """``(name, meta)`` of every span ``run`` opens, seen as a profiler
+    session would see them."""
+    import jax
+
+    notes = []
+
+    class Spy:
+        def __init__(self, name, **meta):
+            notes.append((name, meta))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Spy)
+    _profiling(monkeypatch)
+    run()
+    monkeypatch.undo()
+    return notes
+
+
+@pytest.mark.parametrize("n_devices", [1, 3])
+def test_a_group_span_names_the_chip_it_ran_on(tracer, monkeypatch,
+                                               n_devices):
+    """Several devices place their groups on chips (``d mod count`` on
+    this host's devices) and each group's span says which; one device
+    places nothing."""
+    ctx = BlasxContext(RuntimeConfig(n_devices=n_devices, mode="sim",
+                                     backend="jax"), tile=32,
+                       dtype="float32")
+    a = np.ones((128, 128), np.float32)
+    notes = _annotations(monkeypatch, lambda: ctx.gemm(a, a))
+    groups = [meta for name, meta in notes if name == "blasx.group"]
+    assert groups
+    if n_devices == 1:
+        assert all("chip" not in meta for meta in groups)
+    else:
+        assert {meta["chip"] for meta in groups} <= set(ctx.runtime.chips)
+
+
+class _Held:
+    """A backend that holds each group, so that peers find nothing to do
+    meanwhile."""
+
+    def __init__(self, inner):
+        self.inner, self.name = inner, inner.name
+
+    def run_group(self, key, a, b):
+        time.sleep(0.1)
+        return self.inner.run_group(key, a, b)
+
+
+@pytest.mark.parametrize("mode", ["sim", "threads"])
+def test_a_parked_worker_is_a_wait_span_in_threads_mode_only(tracer, mode):
+    # one task for three devices: two of them park until it is done
+    ctx = BlasxContext(RuntimeConfig(n_devices=3, mode=mode, backend="jax"),
+                       tile=64, dtype="float32")
+    ctx.runtime.backend = _Held(ctx.runtime.backend)
+    a = np.ones((64, 64), np.float32)
+    tracer.enable()
+    ctx.gemm(a, a)
+    spans = tracer.snapshot()["spans"]
+    if mode == "threads":
+        assert spans["blasx.wait"]["count"] > 0
+        assert spans["blasx.wait"]["self_s"] > 0
+    else:
+        assert "blasx.wait" not in spans

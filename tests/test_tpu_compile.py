@@ -88,3 +88,15 @@ def test_distributed_gemm_ring_compiles_for_2x2(topo):
         x, y, mesh, mode="ring")).lower(a, b).compile()
     assert "collective-permute" in compiled.as_text()
     assert len(compiled.output_shardings.device_set) == 4
+
+
+@pytest.mark.parametrize("chip", range(4))
+def test_pod_cell_group_compiles_on_every_chip_of_the_2x2(topo, chip):
+    """A 16384^2 bfloat16 GEMM over four runtime devices runs four-task
+    groups of 16 k-steps at tile 1024 on each chip of the host."""
+    sharding = SingleDeviceSharding(topo.devices[chip])
+    fn = _batched_pallas_contract(16, 1024, 1024, 1024, "bfloat16", False)
+    compiled = fn.lower(*_group_operands(sharding, (4, 16, 1024),
+                                         "bfloat16")).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.output_shardings.device_set == sharding.device_set
