@@ -1,7 +1,8 @@
 """Batched XLA backend: a whole step group in one jitted dispatch.
 
-The group's tiles are stacked into ``(G, steps, m, k)`` / ``(G, steps,
-k, n)`` and the whole thing runs as a single jit-compiled call: the
+The group's tiles go to the chip as they are, one ``device_put`` for
+the group, and are stacked there into ``(G, steps, m, k)`` / ``(G,
+steps, k, n)``; the whole thing runs as a single jit-compiled call: the
 per-item k-chains are folded into one ``(m, steps*k) @ (steps*k, n)``
 contraction — a task's entire k-loop becomes ONE long-K GEMM (the
 Stream-K-style work-centric unit) — and the G items ride a single
@@ -29,6 +30,7 @@ pins float32 inputs.
 """
 from __future__ import annotations
 
+import functools
 import threading
 from typing import Sequence
 
@@ -78,64 +80,98 @@ def engine_dtype(want: str) -> str:
     return want
 
 
-def stack_items(key: StepGroupKey, a_tiles: Sequence[np.ndarray],
-                b_tiles: Sequence[np.ndarray]):
-    """(G*steps) tile lists -> contiguous (G, steps, m, k) /
-    (G, steps, k, n) staging buffers in the engine dtype (one fused
-    cast-copy per tile; halves transfer bytes for f64-stored data on a
-    32-bit engine)."""
-    g = len(a_tiles) // key.steps
-    eng = engine_dtype(key.dtype)
-    a = np.empty((len(a_tiles), key.m, key.k), dtype=eng)
-    b = np.empty((len(b_tiles), key.k, key.n), dtype=eng)
-    for i, tile in enumerate(a_tiles):
-        a[i] = tile
-    for i, tile in enumerate(b_tiles):
-        b[i] = tile
-    return (a.reshape(g, key.steps, key.m, key.k),
-            b.reshape(g, key.steps, key.k, key.n))
+def _as_sent(tiles: Sequence[np.ndarray], eng: str):
+    """One side of a group as it goes to the chip: each tile as it is
+    where it is contiguous in the engine dtype, or as the contiguous
+    array it is the ``.T`` view of, the whole side turned where more of
+    its tiles are such views (the chip turns them back).  A tile that
+    is neither, or of another dtype, is copied or cast on the host.
+    Returns the tiles to send, whether they go transposed, and the
+    bytes copied."""
+    transposed = (sum(t.flags.f_contiguous for t in tiles)
+                  > sum(t.flags.c_contiguous for t in tiles))
+    sent, copied = [], 0
+    for tile in tiles:
+        view = tile.T if transposed else tile
+        out = np.ascontiguousarray(view, dtype=eng)
+        if out is not view:
+            copied += out.nbytes
+        sent.append(out)
+    return tuple(sent), transposed, copied
 
 
-# (kernel, item shapes, dtype, chip, items) of every smaller group
-# compiled ahead of its first use
+@build_once
+def _stacked(fn):
+    """``fn`` on a group's tiles as they arrived on the chip: one jitted
+    call that stacks each side's ``G * steps`` tiles (item-major) into
+    the ``(G, steps, m, k)`` / ``(G, steps, k, n)`` operands ``fn``
+    takes, turning back a side sent as its tiles' transposes, and runs
+    ``fn`` inline.  ``steps`` and the two turns are static: one compile
+    per group shape, dtype and orientation of its sides."""
+    import jax
+    import jax.numpy as jnp
+
+    @functools.partial(jax.jit, static_argnums=(2, 3, 4))
+    def run(a_tiles, b_tiles, steps, turn_a, turn_b):
+        a, b = jnp.stack(a_tiles), jnp.stack(b_tiles)
+        if turn_a:
+            a = jnp.swapaxes(a, 1, 2)
+        if turn_b:
+            b = jnp.swapaxes(b, 1, 2)
+        g = a.shape[0] // steps
+        return fn(a.reshape(g, steps, *a.shape[1:]),
+                  b.reshape(g, steps, *b.shape[1:]))
+
+    return run
+
+
+# (wrapper, tile shapes, dtype, steps, turns, chip, items) of every
+# smaller group compiled ahead of its first use
 _SMALLER: set = set()
 _SMALLER_LOCK = threading.Lock()
 
 
-def _compile_smaller_groups(fn, a, b, chip) -> None:
-    """Run ``fn`` once on every group of fewer items of this shape, the
+def _compile_smaller_groups(run, a, b, steps, turns, chip) -> None:
+    """Run ``run`` once on every group of fewer items of this shape, the
     first time a placed chip sees the shape: a threads-mode call ends
     with smaller groups on whichever chips finish last or steal, and a
     compile there would stall that chip in the middle of a later call."""
-    sig = (fn, a.shape[1:], b.shape[1:], str(a.dtype), chip.id)
+    sig = (run, a[0].shape, b[0].shape, str(a[0].dtype), steps, turns,
+           chip.id)
     with _SMALLER_LOCK:
-        todo = [g for g in range(1, a.shape[0]) if (sig, g) not in _SMALLER]
+        todo = [g for g in range(1, len(a) // steps)
+                if (sig, g) not in _SMALLER]
         _SMALLER.update((sig, g) for g in todo)
     for g in todo:
-        fn(a[:g], b[:g]).block_until_ready()
+        run(a[:g * steps], b[:g * steps], steps, *turns).block_until_ready()
 
 
 def run_staged(fn, key: StepGroupKey, a_tiles: Sequence[np.ndarray],
                b_tiles: Sequence[np.ndarray], engine: str) -> GroupResult:
-    """One group on its chip: stage its tiles on the host, hand them to
-    the chip the runtime placed this worker on (:func:`placed_chip`) with
-    a ``device_put`` waited on (so ``blasx.h2d`` ends where
-    ``blasx.kernel`` starts), run the jitted ``fn(a, b)`` there, where
-    its operands are, and fetch its products in the group's dtype.  On a
-    placed chip, the first group of a shape also compiles its smaller
-    groups (:func:`_compile_smaller_groups`)."""
+    """One group on its chip: hand its tiles as they are (:func:`_as_sent`)
+    to the chip the runtime placed this worker on (:func:`placed_chip`)
+    with one ``device_put`` waited on (so ``blasx.h2d`` ends where
+    ``blasx.kernel`` starts), stack them there and run the jitted
+    ``fn(a, b)`` in the same call (:func:`_stacked`), and fetch its
+    products in the group's dtype.  On a placed chip, the first group of
+    a shape also compiles its smaller groups
+    (:func:`_compile_smaller_groups`)."""
     import jax
 
     chip = placed_chip()
+    eng = engine_dtype(key.dtype)
     with telemetry.span("blasx.stage"):
-        a, b = stack_items(key, a_tiles, b_tiles)
+        a, turn_a, copied_a = _as_sent(a_tiles, eng)
+        b, turn_b, copied_b = _as_sent(b_tiles, eng)
+        telemetry.count("stage_copy_bytes", copied_a + copied_b)
     with telemetry.span("blasx.h2d"):
-        telemetry.count("h2d_bytes", a.nbytes + b.nbytes)
+        telemetry.count("h2d_bytes", sum(t.nbytes for t in a + b))
         a, b = jax.block_until_ready(jax.device_put((a, b), chip))
+    run = _stacked(fn)
     if chip is not None:
-        _compile_smaller_groups(fn, a, b, chip)
+        _compile_smaller_groups(run, a, b, key.steps, (turn_a, turn_b), chip)
     with telemetry.span("blasx.kernel"):
-        out = fn(a, b).block_until_ready()
+        out = run(a, b, key.steps, turn_a, turn_b).block_until_ready()
     with telemetry.span("blasx.d2h"):
         out = np.asarray(out)
         if out.dtype != np.dtype(key.dtype):

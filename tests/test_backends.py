@@ -1,6 +1,8 @@
 """Execution-backend suite: NumPy/JAX/Pallas parity across all six L3
 routines x transpose/uplo/side variants, batched-dispatch launch
-accounting, and backend selection through every API layer.
+accounting, backend selection through every API layer, and how a group
+goes to the device: its tiles as they are, stacked there, with the
+bytes still copied on the host counted (``stage_copy_bytes``).
 
 Parity runs the full pipeline (taskize -> schedule -> batched backend
 dispatch -> epilogue) on 2 simulated devices with ragged edge tiles,
@@ -12,10 +14,20 @@ comparison apples-to-apples.
 The heaviest Pallas cases (interpret mode on CPU) are marked slow;
 one case per routine stays in the fast lane.
 """
+import dataclasses
+import importlib.util
+import sys
+from pathlib import Path
+
 import numpy as np
 import pytest
 
+from repro import telemetry
 from repro.backends import available_backends, create_backend
+from repro.backends.base import StepGroupKey
+from repro.backends.jax_backend import JaxBackend
+from repro.backends.numpy_backend import NumpyBackend
+from repro.backends.pallas_backend import PallasBackend
 from repro.core import blas3
 from repro.core.runtime import BlasxRuntime, RuntimeConfig
 
@@ -256,3 +268,190 @@ def test_execute_false_skips_dispatch():
     ls = rt.launch_stats()
     assert ls["tasks"] > 0
     assert ls["kernel_launches"] == 0 and ls["steps"] == 0
+
+
+# ============================== a group's tiles go to the device as they are
+STAGED = ["float32", "bfloat16", "b_turned", "float64", "views",
+          "ragged_fallback"]
+
+
+def _tiles(rng, shape, n, dtype=np.float32):
+    return [rng.standard_normal(shape).astype(dtype) for _ in range(n)]
+
+
+def _staged_case(name):
+    """One step group as ``run_group`` receives it: (key, A tiles, B
+    tiles, bytes the host still has to copy or cast to send it).  Two
+    items of three k-steps, except the ragged fallback's three single
+    steps."""
+    import ml_dtypes
+
+    rng = np.random.default_rng(7)
+    key = StepGroupKey("gemm", False, False, "full", "full", 32, 24, 16,
+                       "float32", steps=3)
+    n = 2 * key.steps
+    a, b = _tiles(rng, (32, 24), n), _tiles(rng, (24, 16), n)
+    if name == "float32":
+        return key, a, b, 0
+    if name == "bfloat16":
+        cast = [t.astype(ml_dtypes.bfloat16) for t in a + b]
+        return (dataclasses.replace(key, dtype="bfloat16"), cast[:n],
+                cast[n:], 0)
+    if name == "b_turned":
+        # a SYRK's B side: each tile the ``.T`` view of a cached tile
+        turned = [t.T for t in _tiles(rng, (16, 24), n)]
+        return dataclasses.replace(key, op="syrk", transb=True), a, turned, 0
+    if name == "float64":
+        # a float64 group on the 32-bit engine: every tile cast
+        wide = [t.astype(np.float64) for t in a + b]
+        return (dataclasses.replace(key, dtype="float64"), wide[:n],
+                wide[n:], sum(t.nbytes for t in a + b))
+    if name == "views":
+        # A tiles read straight out of a wider matrix: neither contiguous
+        # nor the transpose of a contiguous array
+        wide = rng.standard_normal((32 * n, 40)).astype(np.float32)
+        views = [wide[32 * i:32 * (i + 1), 8:32] for i in range(n)]
+        return key, views, b, sum(t.nbytes for t in a)
+    assert name == "ragged_fallback"
+    # single steps of a TRMM's triangular diagonal tile at a ragged edge
+    # (24 of a 32 tile): the Pallas backend hands these to the JAX path
+    key = StepGroupKey("trmm", False, False, "tri_l", "full", 24, 24, 8,
+                       "float32", steps=1)
+    return key, [np.tril(t) for t in _tiles(rng, (24, 24), 3)], \
+        _tiles(rng, (24, 8), 3), 0
+
+
+@pytest.fixture
+def tracer():
+    telemetry.reset()
+    yield telemetry
+    telemetry.disable()
+    telemetry.reset()
+
+
+@pytest.mark.parametrize("name", STAGED)
+@pytest.mark.parametrize("backend", [JaxBackend, PallasBackend],
+                         ids=["jax", "pallas"])
+def test_a_group_goes_to_the_device_as_its_tiles(tracer, backend, name):
+    """Products as the numpy engine's; ``h2d_bytes`` exactly the tiles'
+    bytes in the engine dtype; ``stage_copy_bytes`` only what could not
+    go as it is."""
+    import jax
+
+    assert not jax.config.jax_enable_x64     # the 32-bit engine
+    key, a, b, copied = _staged_case(name)
+    tracer.enable()
+    got = backend().run_group(key, a, b)
+    tracer.disable()
+    want = NumpyBackend().run_group(
+        key, [t.astype(np.float64) for t in a],
+        [t.astype(np.float64) for t in b]).products
+    assert len(got.products) == len(want) == len(a) // key.steps
+    # bfloat16 output: one rounding of each entry; float32 otherwise
+    tol = 2.0 ** -8 if key.dtype == "bfloat16" else 1e-5
+    for g, w in zip(got.products, want):
+        assert g.dtype == np.dtype(key.dtype)
+        np.testing.assert_allclose(g.astype(np.float64), w, rtol=tol,
+                                   atol=tol * np.abs(w).max())
+    engine_bytes = 2 if key.dtype == "bfloat16" else 4
+    counters = tracer.snapshot()["counters"]
+    assert counters["h2d_bytes"] == sum(t.size for t in a + b) * engine_bytes
+    assert counters["stage_copy_bytes"] == copied
+
+
+def test_no_host_buffer_holds_the_whole_group(monkeypatch):
+    """The tiles go to the device one array each: no ``(G*steps, m, k)``
+    staging buffer is made on the host."""
+    from repro.backends import jax_backend
+
+    assert not hasattr(jax_backend, "stack_items")
+    key, a, b, _ = _staged_case("float32")
+    made = []
+    for fn in ("empty", "stack", "concatenate"):
+        real = getattr(np, fn)
+
+        def spy(*args, _real=real, **kw):
+            out = _real(*args, **kw)
+            made.append(out.shape)
+            return out
+
+        monkeypatch.setattr(np, fn, spy)
+    JaxBackend().run_group(key, a, b)
+    monkeypatch.undo()
+    group = {(len(a), key.m, key.k), (len(b), key.k, key.n),
+             (len(a) // key.steps, key.steps, key.m, key.k),
+             (len(b) // key.steps, key.steps, key.k, key.n)}
+    assert not group & set(made)
+
+
+# the benchmark's reader of ``stage_copy_bytes``
+STAGE_COPY = "stage_copy_bytes_per_call.l3"
+
+
+def _bench():
+    """The benchmark package and the shrink of its cells to a test size."""
+    root = Path(__file__).resolve().parent.parent
+    if str(root) not in sys.path:
+        sys.path.insert(0, str(root))
+    spec = importlib.util.spec_from_file_location(
+        "bench_tests_conftest", root / "tests" / "bench" / "conftest.py")
+    conftest = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(conftest)
+    return conftest.shrink
+
+
+def _record(calls):
+    from bench.run import Record
+    from bench.spans import Spans
+
+    rec = Record(window_s=10.0, spans=Spans(annotate=False), groups=0,
+                 compiles=0)
+    rec.spans.calls = calls
+    return rec
+
+
+@pytest.mark.parametrize("cell", ["n8192.gemm", "n8192.chol_update",
+                                  "n8192.gemm_fresh", "pod.ring_gemm"])
+def test_no_cell_copies_a_tile_on_the_host(tracer, cell):
+    """Each cell at a test size, one call of its loop: every tile goes as
+    it is, the Cholesky update's SYRK B side as the tiles it turns."""
+    import jax  # noqa: F401  (registers bfloat16 with numpy)
+
+    from bench.cell import find_cell, loop_class, metric_reader
+
+    c = _bench()(find_cell(cell))
+    loop = loop_class(c)(c, 2 ** 31 + 5)
+    try:
+        loop.warm()
+        tracer.reset()
+        tracer.enable()
+        loop.iterate(0)
+        tracer.disable()
+    finally:
+        loop.close()
+    counters = tracer.snapshot()["counters"]
+    assert counters["h2d_bytes"] > 0
+    assert metric_reader(STAGE_COPY)(_record(1)) == 0.0
+
+
+def test_the_reader_counts_per_api_call_and_is_silent_without_a_count(
+        tracer):
+    _bench()
+    from bench.cell import metric_reader
+
+    read = metric_reader(STAGE_COPY)
+    # nothing recorded yet
+    assert read(_record(3)) is None
+    key, a, b, copied = _staged_case("views")
+    tracer.enable()
+    with tracer.span("blasx.call"):
+        JaxBackend().run_group(key, a, b)
+    tracer.disable()
+    assert read(_record(2)) == copied / 2
+    # a library that records spans but keeps no such counter
+    tracer.reset()
+    tracer.enable()
+    with tracer.span("blasx.call"):
+        tracer.count("h2d_bytes", 8)
+    tracer.disable()
+    assert read(_record(1)) is None

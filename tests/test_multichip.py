@@ -287,3 +287,58 @@ def test_a_placed_chip_compiles_the_smaller_groups_with_its_first():
         assert len(compiles) > before
     finally:
         jax.monitoring.unregister_event_duration_listener(listen)
+
+
+def test_a_threads_mode_call_compiles_its_tail_groups_with_its_first():
+    """16 tasks over four threads-mode workers, placed on one chip here:
+    the call ends with groups of 1 to 3 tasks, whose on-device stacking
+    was compiled with the chip's first group of the shape, so a second
+    call compiles nothing."""
+    import jax.monitoring
+
+    from repro.backends.base import StepGroupKey, on_chip
+
+    compiles, items = [], []
+
+    def listen(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    # a tile no other test uses: the compile caches are the process's
+    ctx = BlasxContext(RuntimeConfig(n_devices=4, mode="threads",
+                                     backend="jax"), tile=36,
+                       dtype="float32")
+    inner = ctx.runtime.backend
+
+    class Sizes:
+        name = inner.name
+
+        def run_group(self, key, a, b):
+            items.append(len(a) // key.steps)
+            return inner.run_group(key, a, b)
+
+    ctx.runtime.backend = Sizes()
+    rng = np.random.default_rng(3)
+    a, b = (rng.standard_normal((144, 144)).astype(np.float32)
+            for _ in range(2))
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        ctx.gemm(a, b)
+        assert compiles
+        first = len(compiles)
+        items.clear()
+        out = ctx.gemm(a, b).array()
+        assert len(compiles) == first
+        # every smaller group, whether or not a call has run one yet
+        key = StepGroupKey("gemm", False, False, "full", "full", 36, 36,
+                           36, "float32", steps=4)
+        with on_chip(0):
+            for g in (1, 2, 3):
+                tiles = [np.ones((36, 36), np.float32)] * (4 * g)
+                assert len(inner.run_group(key, tiles, tiles).products) == g
+        assert len(compiles) == first
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+        ctx.close()
+    assert sum(items) == 16 and min(items) < 4
+    np.testing.assert_allclose(out, a @ b, rtol=1e-4, atol=1e-4)

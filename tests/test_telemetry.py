@@ -179,8 +179,11 @@ def test_the_call_path_opens_every_span_and_self_times_add_up(tracer):
     # the library's spans cover its calls: little is left between them
     assert outer["self_s"] < 0.01 * wall
     # syrk: 10 lower tiles of a 4x4 grid, one 4-step item each, 4 tiles
-    # of 32x32 float32 per operand; gemm: 16 items of 4 steps
-    assert snap["counters"] == {"h2d_bytes": (16 + 10) * 2 * 4 * 32 * 32 * 4}
+    # of 32x32 float32 per operand; gemm: 16 items of 4 steps.  Every
+    # tile goes to the device as it is, syrk's B side as the contiguous
+    # tiles it is the transpose of: nothing is copied on the host
+    assert snap["counters"] == {"h2d_bytes": (16 + 10) * 2 * 4 * 32 * 32 * 4,
+                                "stage_copy_bytes": 0}
 
 
 def test_host_transposes_are_counted(tracer):

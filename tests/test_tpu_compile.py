@@ -15,7 +15,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.backends.jax_backend import _group_contract
+from repro.backends.jax_backend import _group_contract, _stacked
 from repro.backends.pallas_backend import _batched_pallas_contract
 from repro.core.distributed import distributed_gemm
 
@@ -58,6 +58,15 @@ def _group_operands(sharding, group, dtype):
             jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding))
 
 
+def _group_tiles(sharding, group, dtype):
+    """A group's A and B tiles as ``run_staged`` sends them: one array
+    per tile, item-major."""
+    g, steps, tile = group
+    leaf = jax.ShapeDtypeStruct((tile, tile), jnp.dtype(dtype),
+                                sharding=sharding)
+    return (leaf,) * (g * steps), (leaf,) * (g * steps)
+
+
 @pytest.mark.parametrize("dtype", DTYPES)
 @pytest.mark.parametrize("group", GROUPS, ids=str)
 def test_pallas_group_contract_compiles_to_mosaic(one_chip, group, dtype):
@@ -98,5 +107,40 @@ def test_pod_cell_group_compiles_on_every_chip_of_the_2x2(topo, chip):
     fn = _batched_pallas_contract(16, 1024, 1024, 1024, "bfloat16", False)
     compiled = fn.lower(*_group_operands(sharding, (4, 16, 1024),
                                          "bfloat16")).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.output_shardings.device_set == sharding.device_set
+
+
+@pytest.mark.parametrize("turn_b", [False, True], ids=["as_is", "turned"])
+def test_pallas_group_stacked_on_the_chip_compiles_to_mosaic(one_chip,
+                                                             turn_b):
+    """The 8192^3 float32 GEMM's four-task group, its tiles stacked on
+    the chip (B turned back there, as a SYRK's B side is sent)."""
+    fn = _stacked(_batched_pallas_contract(8, 1024, 1024, 1024, "float32",
+                                           False))
+    a, b = _group_tiles(one_chip, (4, 8, 1024), "float32")
+    compiled = fn.lower(a, b, 8, False, turn_b).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.output_shardings.device_set == one_chip.device_set
+
+
+@pytest.mark.parametrize("turn_b", [False, True], ids=["as_is", "turned"])
+def test_jax_group_stacked_on_the_chip_compiles(one_chip, turn_b):
+    a, b = _group_tiles(one_chip, (4, 8, 1024), "float32")
+    compiled = _stacked(_group_contract()).lower(a, b, 8, False,
+                                                 turn_b).compile()
+    assert compiled.output_shardings.device_set == one_chip.device_set
+    assert "operand_precision={highest,highest}" in compiled.as_text()
+
+
+@pytest.mark.parametrize("chip", range(4))
+def test_pod_cell_group_stacked_on_every_chip_of_the_2x2(topo, chip):
+    """The 16384^2 bfloat16 cell's four-task groups of 16 k-steps, their
+    tiles stacked on the chip each runtime device is placed on."""
+    sharding = SingleDeviceSharding(topo.devices[chip])
+    fn = _stacked(_batched_pallas_contract(16, 1024, 1024, 1024,
+                                           "bfloat16", False))
+    a, b = _group_tiles(sharding, (4, 16, 1024), "bfloat16")
+    compiled = fn.lower(a, b, 16, False, False).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.output_shardings.device_set == sharding.device_set
