@@ -30,11 +30,7 @@ Two classes of checks:
   the serving lane's flags hold (quota'd tenant isolation + its
   fails-without counterpart, exact admission rejections, interactive
   before batch, loaded-vs-unloaded p99 bound — see
-  benchmarks/serving.py), and the pod lane's flags hold (staged
-  makespan <= unstaged on every beyond-HBM shape; ici_busy_s ==
-  ici_bytes/ici_bw on every device; the executing parity DGEMM is
-  bitwise-equal across staged / unstaged / accelerator runs — see
-  benchmarks/pod.py).
+  benchmarks/serving.py).
 * **Regressions vs baseline** — metrics compared against
   ``benchmarks/baseline.json`` with a tolerance (default 20%; CI
   passes 35%): the jax-vs-numpy speedup ratio and the deterministic
@@ -138,7 +134,6 @@ def check_invariants(gate: Gate, pr_rows: Dict[str, dict]) -> None:
     check_overlap_invariants(gate, pr_rows)
     check_autotune_invariants(gate, pr_rows)
     check_serving_invariants(gate, pr_rows)
-    check_pod_invariants(gate, pr_rows)
 
 
 def check_overlap_invariants(gate: Gate, pr_rows: Dict[str, dict]) -> None:
@@ -282,41 +277,6 @@ def check_serving_invariants(gate: Gate, pr_rows: Dict[str, dict]) -> None:
             gate.note(f"OK   invariant: serving {flag}")
 
 
-def check_pod_invariants(gate: Gate, pr_rows: Dict[str, dict]) -> None:
-    """Structural properties of the pod lane (benchmarks/pod.py), all
-    virtual-clock deterministic: on every deep-k beyond-HBM shape,
-    staging panels through the 3-level cache must not lose to the
-    bypass-to-host baseline; ICI lane busy seconds must equal
-    ``ici_bytes / ici_bw`` exactly on every device of every run; and
-    the executing parity DGEMM must agree bitwise across staged,
-    unstaged and flat-accelerator runs."""
-    summary = pr_rows.get("pod/summary")
-    if summary is None:
-        gate.fail("pod/summary row missing from PR report")
-        return
-    if _num(summary, "staged_le_unstaged_all") != 1:
-        bad = [name for name, row in pr_rows.items()
-               if name.startswith("pod/staged_")
-               and _num(row, "staged_le_unstaged") == 0]
-        gate.fail("invariant: staged makespan must be <= unstaged on "
-                  f"every beyond-HBM shape (violated by: {bad})")
-    else:
-        gate.note("OK   invariant: pod staged makespan <= unstaged on "
-                  "every beyond-HBM shape")
-    if _num(summary, "ici_time_consistent_all") != 1:
-        gate.fail("invariant: ICI lane busy seconds must equal "
-                  "ici_bytes / ici_bw on every device of every pod run")
-    else:
-        gate.note("OK   invariant: pod ici_busy_s == ici_bytes/ici_bw "
-                  "on every device")
-    if _num(summary, "pod_bitwise_equal") != 1:
-        gate.fail("invariant: the executing pod parity DGEMM must agree "
-                  "bitwise across staged / unstaged / accelerator runs")
-    else:
-        gate.note("OK   invariant: pod parity DGEMM bitwise-equal "
-                  "across staged / unstaged / accelerator")
-
-
 def check_regressions(gate: Gate, pr_rows: Dict[str, dict],
                       base_rows: Dict[str, dict], tol: float,
                       gate_gflops: bool) -> None:
@@ -435,21 +395,6 @@ def check_regressions(gate: Gate, pr_rows: Dict[str, dict],
         gate.check_ratio("serving/admission", "rejected",
                          _num(pr, "rejected"), _num(base, "rejected"),
                          tol, higher_is_better=False)
-    # pod lane: virtual-clock staged-vs-unstaged metrics, deterministic
-    pod_names = sorted(name for name in (set(pr_rows) | set(base_rows))
-                       if name.startswith("pod/staged_"))
-    for name in pod_names:
-        pr, base = both(name)
-        if pr is None:
-            continue
-        gate.check_ratio(name, "makespan_staged",
-                         _num(pr, "makespan_staged"),
-                         _num(base, "makespan_staged"),
-                         tol, higher_is_better=False)
-        gate.check_ratio(name, "staged_speedup",
-                         _num(pr, "staged_speedup"),
-                         _num(base, "staged_speedup"),
-                         tol, higher_is_better=True)
 
 
 def main(argv=None) -> int:

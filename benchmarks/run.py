@@ -19,10 +19,9 @@ accumulate across PRs and be gated by ``benchmarks/compare.py``.
   overlap        comm/compute overlap per policy (discrete-event engine)
   autotune       tuned-vs-default config search  (runtime autotuner)
   serving        BlasxServer saturation + tenant isolation (repro.serve)
-  pod            3-level cache staged-vs-unstaged on mesh_shard devices
 
 ``--quick`` runs the fast deterministic subset (the CI bench-smoke
-lane): table1 + backends + overlap + autotune + serving + pod.
+lane): table1 + backends + overlap + autotune + serving.
 """
 from __future__ import annotations
 
@@ -35,7 +34,7 @@ import time
 
 from . import (autotune, backends, bench_context_reuse, fig5_heap,
                fig7_throughput, fig8_load_balance, fig10_tile_size, overlap,
-               pallas_kernel, pod, serving, table1_gemm_fraction,
+               pallas_kernel, serving, table1_gemm_fraction,
                table4_link_model, table5_comm_volume)
 from .common import rows_to_csv
 
@@ -53,7 +52,6 @@ MODULES = [
     ("backends", backends),
     ("overlap", overlap),
     ("serving", serving),
-    ("pod", pod),
 ]
 
 QUICK_MODULES = [
@@ -62,7 +60,6 @@ QUICK_MODULES = [
     ("overlap", overlap),
     ("autotune", autotune),
     ("serving", serving),
-    ("pod", pod),
 ]
 
 

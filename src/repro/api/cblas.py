@@ -156,29 +156,13 @@ def _view(buf, rows: int, cols: int, ld: int, order: int, name: str,
 
 
 def _ctx(ctx: Optional[BlasxContext],
-         backend: Optional[str] = None,
-         device_class: Optional[str] = None,
-         mesh: Optional[int] = None) -> BlasxContext:
+         backend: Optional[str] = None) -> BlasxContext:
     if ctx is not None:
         if backend is not None and ctx.cfg.backend != backend:
             raise ValueError(
                 f"backend={backend!r} conflicts with ctx backend "
                 f"{ctx.cfg.backend!r}")
-        if (device_class is not None
-                and ctx.cfg.device_class != device_class):
-            raise ValueError(
-                f"device_class={device_class!r} conflicts with ctx "
-                f"device class {ctx.cfg.device_class!r}")
-        if mesh is not None and ctx.cfg.mesh_devices != mesh:
-            raise ValueError(
-                f"mesh={mesh} conflicts with ctx mesh_devices "
-                f"{ctx.cfg.mesh_devices}")
         return ctx
-    if device_class is not None or mesh is not None:
-        # pod-tier call without a context: private per-call context
-        # (mirrors blas3's config= semantics)
-        return BlasxContext(backend=backend, device_class=device_class,
-                            mesh=mesh)
     if backend is None:
         return default_context()
     # calls sharing a backend share one warm-cache module context
@@ -187,36 +171,34 @@ def _ctx(ctx: Optional[BlasxContext],
 
 # ============================================= dtype-parameterized bodies
 def _gemm(dtype, order, transa, transb, m, n, k, alpha, A, lda, B, ldb,
-          beta, C, ldc, ctx, backend, tile=None, device_class=None,
-          mesh=None) -> None:
+          beta, C, ldc, ctx, backend, tile=None) -> None:
     ta, tb = _flag(_TRANS, transa, "Trans"), _flag(_TRANS, transb, "Trans")
     ar, ac = (m, k) if ta == "N" else (k, m)
     br, bc = (k, n) if tb == "N" else (n, k)
     Av = _view(A, ar, ac, lda, order, "A", dtype=dtype)
     Bv = _view(B, br, bc, ldb, order, "B", dtype=dtype)
     Cv = _view(C, m, n, ldc, order, "C", writable=True, dtype=dtype)
-    out = _ctx(ctx, backend, device_class, mesh).gemm(Av, Bv, Cv if beta != 0.0 else None,
+    out = _ctx(ctx, backend).gemm(Av, Bv, Cv if beta != 0.0 else None,
                                   alpha=alpha, beta=beta, transa=ta,
                                   transb=tb, tile=tile, dtype=dtype)
     Cv[...] = out.array()
 
 
 def _symm(dtype, order, side, uplo, m, n, alpha, A, lda, B, ldb, beta,
-          C, ldc, ctx, backend, tile=None, device_class=None,
-          mesh=None) -> None:
+          C, ldc, ctx, backend, tile=None) -> None:
     sd, ul = _flag(_SIDE, side, "Side"), _flag(_UPLO, uplo, "Uplo")
     ka = m if sd == "L" else n
     Av = _view(A, ka, ka, lda, order, "A", dtype=dtype)
     Bv = _view(B, m, n, ldb, order, "B", dtype=dtype)
     Cv = _view(C, m, n, ldc, order, "C", writable=True, dtype=dtype)
-    out = _ctx(ctx, backend, device_class, mesh).symm(Av, Bv, Cv if beta != 0.0 else None,
+    out = _ctx(ctx, backend).symm(Av, Bv, Cv if beta != 0.0 else None,
                                   alpha=alpha, beta=beta, side=sd, uplo=ul,
                                   tile=tile, dtype=dtype)
     Cv[...] = out.array()
 
 
 def _syrk(dtype, order, uplo, trans, n, k, alpha, A, lda, beta, C, ldc,
-          ctx, backend, tile=None, device_class=None, mesh=None) -> None:
+          ctx, backend, tile=None) -> None:
     ul, tr = _flag(_UPLO, uplo, "Uplo"), _flag(_TRANS, trans, "Trans")
     ar, ac = (n, k) if tr == "N" else (k, n)
     Av = _view(A, ar, ac, lda, order, "A", dtype=dtype)
@@ -224,48 +206,45 @@ def _syrk(dtype, order, uplo, trans, n, k, alpha, A, lda, beta, C, ldc,
     # BLAS syrk always reads C's uplo triangle (beta scales it), so seed
     # the context call with Cv even for beta == 0 to preserve the
     # untouched opposite triangle in the writeback.
-    out = _ctx(ctx, backend, device_class, mesh).syrk(Av, Cv, alpha=alpha, beta=beta, uplo=ul,
+    out = _ctx(ctx, backend).syrk(Av, Cv, alpha=alpha, beta=beta, uplo=ul,
                                   trans=tr, tile=tile, dtype=dtype)
     Cv[...] = out.array()
 
 
 def _syr2k(dtype, order, uplo, trans, n, k, alpha, A, lda, B, ldb, beta,
-           C, ldc, ctx, backend, tile=None, device_class=None,
-           mesh=None) -> None:
+           C, ldc, ctx, backend, tile=None) -> None:
     ul, tr = _flag(_UPLO, uplo, "Uplo"), _flag(_TRANS, trans, "Trans")
     ar, ac = (n, k) if tr == "N" else (k, n)
     Av = _view(A, ar, ac, lda, order, "A", dtype=dtype)
     Bv = _view(B, ar, ac, ldb, order, "B", dtype=dtype)
     Cv = _view(C, n, n, ldc, order, "C", writable=True, dtype=dtype)
-    out = _ctx(ctx, backend, device_class, mesh).syr2k(Av, Bv, Cv, alpha=alpha, beta=beta,
+    out = _ctx(ctx, backend).syr2k(Av, Bv, Cv, alpha=alpha, beta=beta,
                                    uplo=ul, trans=tr, tile=tile,
                                    dtype=dtype)
     Cv[...] = out.array()
 
 
 def _trmm(dtype, order, side, uplo, transa, diag, m, n, alpha, A, lda,
-          B, ldb, ctx, backend, tile=None, device_class=None,
-          mesh=None) -> None:
+          B, ldb, ctx, backend, tile=None) -> None:
     sd, ul = _flag(_SIDE, side, "Side"), _flag(_UPLO, uplo, "Uplo")
     ta, dg = _flag(_TRANS, transa, "Trans"), _flag(_DIAG, diag, "Diag")
     ka = m if sd == "L" else n
     Av = _view(A, ka, ka, lda, order, "A", dtype=dtype)
     Bv = _view(B, m, n, ldb, order, "B", writable=True, dtype=dtype)
-    out = _ctx(ctx, backend, device_class, mesh).trmm(Av, Bv, alpha=alpha, side=sd, uplo=ul,
+    out = _ctx(ctx, backend).trmm(Av, Bv, alpha=alpha, side=sd, uplo=ul,
                                   transa=ta, diag=dg, tile=tile,
                                   dtype=dtype)
     Bv[...] = out.array()
 
 
 def _trsm(dtype, order, side, uplo, transa, diag, m, n, alpha, A, lda,
-          B, ldb, ctx, backend, tile=None, device_class=None,
-          mesh=None) -> None:
+          B, ldb, ctx, backend, tile=None) -> None:
     sd, ul = _flag(_SIDE, side, "Side"), _flag(_UPLO, uplo, "Uplo")
     ta, dg = _flag(_TRANS, transa, "Trans"), _flag(_DIAG, diag, "Diag")
     ka = m if sd == "L" else n
     Av = _view(A, ka, ka, lda, order, "A", dtype=dtype)
     Bv = _view(B, m, n, ldb, order, "B", writable=True, dtype=dtype)
-    out = _ctx(ctx, backend, device_class, mesh).trsm(Av, Bv, alpha=alpha, side=sd, uplo=ul,
+    out = _ctx(ctx, backend).trsm(Av, Bv, alpha=alpha, side=sd, uplo=ul,
                                   transa=ta, diag=dg, tile=tile,
                                   dtype=dtype)
     Bv[...] = out.array()
@@ -277,69 +256,63 @@ def cblas_dgemm(order, transa, transb, m: int, n: int, k: int,
                 beta: float, C, ldc: int, *,
                 ctx: Optional[BlasxContext] = None,
                 backend: Optional[str] = None,
-                tile=None, device_class: Optional[str] = None,
-                mesh: Optional[int] = None) -> None:
+                tile=None) -> None:
     """C := alpha*op(A)*op(B) + beta*C  (C is m x n, updated in place)."""
     _gemm(np.float64, order, transa, transb, m, n, k, alpha, A, lda,
-          B, ldb, beta, C, ldc, ctx, backend, tile, device_class, mesh)
+          B, ldb, beta, C, ldc, ctx, backend, tile)
 
 
 def cblas_dsymm(order, side, uplo, m: int, n: int, alpha: float,
                 A, lda: int, B, ldb: int, beta: float, C, ldc: int, *,
                 ctx: Optional[BlasxContext] = None,
                 backend: Optional[str] = None,
-                tile=None, device_class: Optional[str] = None,
-                mesh: Optional[int] = None) -> None:
+                tile=None) -> None:
     """C := alpha*A*B + beta*C (Left) or alpha*B*A + beta*C (Right),
     A symmetric with the ``uplo`` triangle stored."""
     _symm(np.float64, order, side, uplo, m, n, alpha, A, lda, B, ldb,
-          beta, C, ldc, ctx, backend, tile, device_class, mesh)
+          beta, C, ldc, ctx, backend, tile)
 
 
 def cblas_dsyrk(order, uplo, trans, n: int, k: int, alpha: float,
                 A, lda: int, beta: float, C, ldc: int, *,
                 ctx: Optional[BlasxContext] = None,
                 backend: Optional[str] = None,
-                tile=None, device_class: Optional[str] = None,
-                mesh: Optional[int] = None) -> None:
+                tile=None) -> None:
     """C := alpha*op(A)*op(A)^T + beta*C on the ``uplo`` triangle."""
     _syrk(np.float64, order, uplo, trans, n, k, alpha, A, lda, beta,
-          C, ldc, ctx, backend, tile, device_class, mesh)
+          C, ldc, ctx, backend, tile)
 
 
 def cblas_dsyr2k(order, uplo, trans, n: int, k: int, alpha: float,
                  A, lda: int, B, ldb: int, beta: float, C, ldc: int, *,
                  ctx: Optional[BlasxContext] = None,
                  backend: Optional[str] = None,
-                 tile=None, device_class: Optional[str] = None,
-                 mesh: Optional[int] = None) -> None:
+                 tile=None) -> None:
     """C := alpha*op(A)*op(B)^T + alpha*op(B)*op(A)^T + beta*C."""
     _syr2k(np.float64, order, uplo, trans, n, k, alpha, A, lda, B, ldb,
-           beta, C, ldc, ctx, backend, tile, device_class, mesh)
+           beta, C, ldc, ctx, backend, tile)
 
 
 def cblas_dtrmm(order, side, uplo, transa, diag, m: int, n: int,
                 alpha: float, A, lda: int, B, ldb: int, *,
                 ctx: Optional[BlasxContext] = None,
                 backend: Optional[str] = None,
-                tile=None, device_class: Optional[str] = None,
-                mesh: Optional[int] = None) -> None:
+                tile=None) -> None:
     """B := alpha*op(tri(A))*B (Left) or alpha*B*op(tri(A)) (Right),
     B (m x n) updated in place."""
     _trmm(np.float64, order, side, uplo, transa, diag, m, n, alpha,
-          A, lda, B, ldb, ctx, backend, tile, device_class, mesh)
+          A, lda, B, ldb, ctx, backend, tile)
 
 
 def cblas_dtrsm(order, side, uplo, transa, diag, m: int, n: int,
                 alpha: float, A, lda: int, B, ldb: int, *,
                 ctx: Optional[BlasxContext] = None,
                 backend: Optional[str] = None,
-                tile=None, device_class: Optional[str] = None,
-                mesh: Optional[int] = None) -> None:
+                tile=None) -> None:
     """Solve op(tri(A))*X = alpha*B (Left) or X*op(tri(A)) = alpha*B
     (Right); X overwrites B (m x n) in place."""
     _trsm(np.float64, order, side, uplo, transa, diag, m, n, alpha,
-          A, lda, B, ldb, ctx, backend, tile, device_class, mesh)
+          A, lda, B, ldb, ctx, backend, tile)
 
 
 # ================================================ single-precision surface
@@ -348,64 +321,58 @@ def cblas_sgemm(order, transa, transb, m: int, n: int, k: int,
                 beta: float, C, ldc: int, *,
                 ctx: Optional[BlasxContext] = None,
                 backend: Optional[str] = None,
-                tile=None, device_class: Optional[str] = None,
-                mesh: Optional[int] = None) -> None:
+                tile=None) -> None:
     """Single-precision GEMM: C := alpha*op(A)*op(B) + beta*C, all
     buffers float32, C updated in place."""
     _gemm(np.float32, order, transa, transb, m, n, k, alpha, A, lda,
-          B, ldb, beta, C, ldc, ctx, backend, tile, device_class, mesh)
+          B, ldb, beta, C, ldc, ctx, backend, tile)
 
 
 def cblas_ssymm(order, side, uplo, m: int, n: int, alpha: float,
                 A, lda: int, B, ldb: int, beta: float, C, ldc: int, *,
                 ctx: Optional[BlasxContext] = None,
                 backend: Optional[str] = None,
-                tile=None, device_class: Optional[str] = None,
-                mesh: Optional[int] = None) -> None:
+                tile=None) -> None:
     """Single-precision SYMM (see :func:`cblas_dsymm`)."""
     _symm(np.float32, order, side, uplo, m, n, alpha, A, lda, B, ldb,
-          beta, C, ldc, ctx, backend, tile, device_class, mesh)
+          beta, C, ldc, ctx, backend, tile)
 
 
 def cblas_ssyrk(order, uplo, trans, n: int, k: int, alpha: float,
                 A, lda: int, beta: float, C, ldc: int, *,
                 ctx: Optional[BlasxContext] = None,
                 backend: Optional[str] = None,
-                tile=None, device_class: Optional[str] = None,
-                mesh: Optional[int] = None) -> None:
+                tile=None) -> None:
     """Single-precision SYRK (see :func:`cblas_dsyrk`)."""
     _syrk(np.float32, order, uplo, trans, n, k, alpha, A, lda, beta,
-          C, ldc, ctx, backend, tile, device_class, mesh)
+          C, ldc, ctx, backend, tile)
 
 
 def cblas_ssyr2k(order, uplo, trans, n: int, k: int, alpha: float,
                  A, lda: int, B, ldb: int, beta: float, C, ldc: int, *,
                  ctx: Optional[BlasxContext] = None,
                  backend: Optional[str] = None,
-                 tile=None, device_class: Optional[str] = None,
-                 mesh: Optional[int] = None) -> None:
+                 tile=None) -> None:
     """Single-precision SYR2K (see :func:`cblas_dsyr2k`)."""
     _syr2k(np.float32, order, uplo, trans, n, k, alpha, A, lda, B, ldb,
-           beta, C, ldc, ctx, backend, tile, device_class, mesh)
+           beta, C, ldc, ctx, backend, tile)
 
 
 def cblas_strmm(order, side, uplo, transa, diag, m: int, n: int,
                 alpha: float, A, lda: int, B, ldb: int, *,
                 ctx: Optional[BlasxContext] = None,
                 backend: Optional[str] = None,
-                tile=None, device_class: Optional[str] = None,
-                mesh: Optional[int] = None) -> None:
+                tile=None) -> None:
     """Single-precision TRMM (see :func:`cblas_dtrmm`)."""
     _trmm(np.float32, order, side, uplo, transa, diag, m, n, alpha,
-          A, lda, B, ldb, ctx, backend, tile, device_class, mesh)
+          A, lda, B, ldb, ctx, backend, tile)
 
 
 def cblas_strsm(order, side, uplo, transa, diag, m: int, n: int,
                 alpha: float, A, lda: int, B, ldb: int, *,
                 ctx: Optional[BlasxContext] = None,
                 backend: Optional[str] = None,
-                tile=None, device_class: Optional[str] = None,
-                mesh: Optional[int] = None) -> None:
+                tile=None) -> None:
     """Single-precision TRSM (see :func:`cblas_dtrsm`)."""
     _trsm(np.float32, order, side, uplo, transa, diag, m, n, alpha,
-          A, lda, B, ldb, ctx, backend, tile, device_class, mesh)
+          A, lda, B, ldb, ctx, backend, tile)

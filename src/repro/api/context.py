@@ -162,14 +162,10 @@ class CallRecord:
     l1_hits: int
     l1_misses: int
     makespan: float        # modeled seconds this call added (sim mode)
-    # pod tier: ICI ring-scatter hops + neighbor-tier serves (0 on
-    # plain accelerator contexts); defaulted so pickled/legacy records
-    # stay constructible
-    ici_bytes: int = 0
 
     @property
     def input_bytes(self) -> int:
-        return self.h2d_bytes + self.d2d_bytes + self.ici_bytes
+        return self.h2d_bytes + self.d2d_bytes
 
 
 class BlasxContext:
@@ -248,9 +244,7 @@ class BlasxContext:
                  backend: Optional[str] = None,
                  dtype=None,
                  auto_tune: Union[bool, str] = False,
-                 tuning_cache=None,
-                 device_class: Optional[str] = None,
-                 mesh: Optional[int] = None):
+                 tuning_cache=None):
         if backend is not None:
             if runtime is not None:
                 if runtime.cfg.backend != backend:
@@ -262,24 +256,6 @@ class BlasxContext:
                                        backend=backend)
             elif config.backend != backend:
                 config = dataclasses.replace(config, backend=backend)
-        # pod-tier knobs: device_class= selects the DeviceClass each
-        # runtime device models; mesh= sets the per-device ring width
-        # and implies the mesh_shard class (a ring of 1 is just an
-        # accelerator, so a bare mesh=N means "make these pod shards")
-        if device_class is not None or mesh is not None:
-            if runtime is not None:
-                raise ValueError(
-                    "device_class=/mesh= cannot be combined with an "
-                    "adopted runtime= (set them on its RuntimeConfig)")
-            config = config or RuntimeConfig(n_devices=1, mode="sim")
-            if device_class is None and config.device_class == "accelerator":
-                device_class = "mesh_shard"
-            changes = {}
-            if device_class is not None:
-                changes["device_class"] = device_class
-            if mesh is not None:
-                changes["mesh_devices"] = mesh
-            config = dataclasses.replace(config, **changes)
         self._owns_runtime = runtime is None
         self.runtime = runtime if runtime is not None else BlasxRuntime(
             config or RuntimeConfig(n_devices=1, mode="sim"))
@@ -493,7 +469,6 @@ class BlasxContext:
             h2d_bytes=after_comm["h2d"] - before_comm["h2d"],
             d2h_bytes=after_comm["d2h"] - before_comm["d2h"],
             d2d_bytes=after_comm["d2d"] - before_comm["d2d"],
-            ici_bytes=after_comm["ici"] - before_comm["ici"],
             tasks=d_tasks, steals=d_steals,
             l1_hits=d_hits, l1_misses=d_miss,
             makespan=rt.makespan() - t0,

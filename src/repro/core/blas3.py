@@ -46,24 +46,17 @@ def _finish(out) -> np.ndarray:
 
 def _context(config: Optional[RuntimeConfig],
              runtime: Optional[BlasxRuntime],
-             backend: Optional[str] = None,
-             device_class: Optional[str] = None,
-             mesh: Optional[int] = None):
+             backend: Optional[str] = None):
     """Resolve the executing context for one legacy call.
 
     ``backend`` selects the execution backend (numpy | jax | pallas)
     for this call; with ``runtime=`` it must match the runtime's own.
-    ``device_class``/``mesh`` select the pod tier (a private context is
-    built for the call — they cannot be combined with ``runtime=``).
 
     Imported lazily: ``repro.api`` depends on ``repro.core`` modules,
     so the dependency must point api -> core at import time."""
     from ..api.context import (BlasxContext, backend_context,
                                default_context)
 
-    if device_class is not None or mesh is not None:
-        return BlasxContext(config, backend=backend, runtime=runtime,
-                            device_class=device_class, mesh=mesh)
     if runtime is not None:
         return BlasxContext(runtime=runtime, backend=backend)
     if config is not None:
@@ -78,10 +71,8 @@ def _context(config: Optional[RuntimeConfig],
 def gemm(A, B, C=None, *, alpha=1.0, beta=0.0, transa="N", transb="N",
          tile=DEFAULT_TILE, config: Optional[RuntimeConfig] = None,
          runtime: Optional[BlasxRuntime] = None,
-         backend: Optional[str] = None, dtype=None,
-         device_class: Optional[str] = None,
-         mesh: Optional[int] = None) -> np.ndarray:
-    ctx = _context(config, runtime, backend, device_class, mesh)
+         backend: Optional[str] = None, dtype=None) -> np.ndarray:
+    ctx = _context(config, runtime, backend)
     return _finish(ctx.gemm(A, B, C, alpha=alpha, beta=beta,
                             transa=transa, transb=transb, tile=tile,
                             dtype=dtype))
@@ -91,10 +82,8 @@ def gemm(A, B, C=None, *, alpha=1.0, beta=0.0, transa="N", transb="N",
 def syrk(A, C=None, *, alpha=1.0, beta=0.0, uplo="U", trans="N",
          tile=DEFAULT_TILE, config: Optional[RuntimeConfig] = None,
          runtime: Optional[BlasxRuntime] = None,
-         backend: Optional[str] = None, dtype=None,
-         device_class: Optional[str] = None,
-         mesh: Optional[int] = None) -> np.ndarray:
-    ctx = _context(config, runtime, backend, device_class, mesh)
+         backend: Optional[str] = None, dtype=None) -> np.ndarray:
+    ctx = _context(config, runtime, backend)
     return _finish(ctx.syrk(A, C, alpha=alpha, beta=beta, uplo=uplo,
                             trans=trans, tile=tile, dtype=dtype))
 
@@ -103,10 +92,8 @@ def syrk(A, C=None, *, alpha=1.0, beta=0.0, uplo="U", trans="N",
 def syr2k(A, B, C=None, *, alpha=1.0, beta=0.0, uplo="U", trans="N",
           tile=DEFAULT_TILE, config: Optional[RuntimeConfig] = None,
           runtime: Optional[BlasxRuntime] = None,
-          backend: Optional[str] = None, dtype=None,
-          device_class: Optional[str] = None,
-          mesh: Optional[int] = None) -> np.ndarray:
-    ctx = _context(config, runtime, backend, device_class, mesh)
+          backend: Optional[str] = None, dtype=None) -> np.ndarray:
+    ctx = _context(config, runtime, backend)
     return _finish(ctx.syr2k(A, B, C, alpha=alpha, beta=beta, uplo=uplo,
                              trans=trans, tile=tile, dtype=dtype))
 
@@ -115,10 +102,8 @@ def syr2k(A, B, C=None, *, alpha=1.0, beta=0.0, uplo="U", trans="N",
 def symm(A, B, C=None, *, alpha=1.0, beta=0.0, side="L", uplo="U",
          tile=DEFAULT_TILE, config: Optional[RuntimeConfig] = None,
          runtime: Optional[BlasxRuntime] = None,
-         backend: Optional[str] = None, dtype=None,
-         device_class: Optional[str] = None,
-         mesh: Optional[int] = None) -> np.ndarray:
-    ctx = _context(config, runtime, backend, device_class, mesh)
+         backend: Optional[str] = None, dtype=None) -> np.ndarray:
+    ctx = _context(config, runtime, backend)
     return _finish(ctx.symm(A, B, C, alpha=alpha, beta=beta, side=side,
                             uplo=uplo, tile=tile, dtype=dtype))
 
@@ -127,10 +112,8 @@ def symm(A, B, C=None, *, alpha=1.0, beta=0.0, side="L", uplo="U",
 def trmm(A, B, *, alpha=1.0, side="L", uplo="U", transa="N", diag="N",
          tile=DEFAULT_TILE, config: Optional[RuntimeConfig] = None,
          runtime: Optional[BlasxRuntime] = None,
-         backend: Optional[str] = None, dtype=None,
-         device_class: Optional[str] = None,
-         mesh: Optional[int] = None) -> np.ndarray:
-    ctx = _context(config, runtime, backend, device_class, mesh)
+         backend: Optional[str] = None, dtype=None) -> np.ndarray:
+    ctx = _context(config, runtime, backend)
     return _finish(ctx.trmm(A, B, alpha=alpha, side=side, uplo=uplo,
                             transa=transa, diag=diag, tile=tile,
                             dtype=dtype))
@@ -140,10 +123,8 @@ def trmm(A, B, *, alpha=1.0, side="L", uplo="U", transa="N", diag="N",
 def trsm(A, B, *, alpha=1.0, side="L", uplo="U", transa="N", diag="N",
          tile=DEFAULT_TILE, config: Optional[RuntimeConfig] = None,
          runtime: Optional[BlasxRuntime] = None,
-         backend: Optional[str] = None, dtype=None,
-         device_class: Optional[str] = None,
-         mesh: Optional[int] = None) -> np.ndarray:
-    ctx = _context(config, runtime, backend, device_class, mesh)
+         backend: Optional[str] = None, dtype=None) -> np.ndarray:
+    ctx = _context(config, runtime, backend)
     return _finish(ctx.trsm(A, B, alpha=alpha, side=side, uplo=uplo,
                             transa=transa, diag=diag, tile=tile,
                             dtype=dtype))

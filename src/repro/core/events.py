@@ -10,8 +10,7 @@ schedule over explicit *resources*:
   one task of a batch runs on one lane (fetch -> compute -> write-back
   in program order), so concurrent tasks overlap exactly where their
   per-lane chains allow it;
-* **link timelines** — per-device H2D, D2D (P2P), D2H and (pod tier)
-  ICI lanes.  With
+* **link timelines** — per-device H2D, D2D (P2P) and D2H lanes.  With
   ``RuntimeConfig.shared_host_link`` every device's H2D (and D2H)
   transfers serialize on ONE host lane per direction at full link
   bandwidth — the paper's "cuBLAS-XT overloads the PCI-E" contention
@@ -48,9 +47,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 LANE_H2D = 100
 LANE_D2D = 101
 LANE_D2H = 102
-LANE_ICI = 103  # pod tier: inter-chip ring hops of a mesh_shard device
-LINK_LANES = {"h2d": LANE_H2D, "d2d": LANE_D2D, "d2h": LANE_D2H,
-              "ici": LANE_ICI}
+LINK_LANES = {"h2d": LANE_H2D, "d2d": LANE_D2D, "d2h": LANE_D2H}
 
 TRACE_SCHEMA = 1
 # recording cap: a runaway metadata-scale session stops *recording*
@@ -62,17 +59,16 @@ MAX_TRACE_SPANS = 1_000_000
 class TimedXfer:
     """One modeled transfer: direction, payload and link seconds.
 
-    ``src`` names the *serving* device of a P2P (d2d) or neighbor-tier
-    (ici) transfer; the engine then reserves the server's egress lane,
-    so contention lands on the device actually being drained.  ``-1``
-    (h2d/d2h, or legacy callers) keeps the transfer on the requester's
-    own lane."""
+    ``src`` names the *serving* device of a P2P (d2d) transfer; the
+    engine then reserves the server's egress lane, so contention lands
+    on the device actually being drained.  ``-1`` (h2d/d2h, or legacy
+    callers) keeps the transfer on the requester's own lane."""
 
-    kind: str       # "h2d" | "d2d" | "d2h" | "ici"
+    kind: str       # "h2d" | "d2d" | "d2h"
     nbytes: int
     secs: float
     label: str = ""
-    src: int = -1   # serving device of a d2d/ici transfer (-1 = requester)
+    src: int = -1   # serving device of a d2d transfer (-1 = requester)
 
 
 @dataclasses.dataclass
@@ -221,10 +217,6 @@ class EventEngine:
         # P2P rides dedicated switch lanes: per-device, no cross-device
         # contention (cfg comment in runtime.RuntimeConfig)
         self._d2d = [LinkTimeline() for _ in range(n)]
-        # pod tier: per-device ICI links (a mesh_shard device's ring
-        # hops and neighbor-tier fetches); dedicated point-to-point
-        # fabric, so no cross-device contention either
-        self._ici = [LinkTimeline() for _ in range(n)]
         self.spans: List[Span] = []
         self.truncated = False
         self.record = bool(getattr(cfg, "record_trace", True))
@@ -232,7 +224,7 @@ class EventEngine:
     # ------------------------------------------------------------- helpers
     def _link(self, kind: str, device: int) -> LinkTimeline:
         return {"h2d": self._h2d, "d2d": self._d2d,
-                "d2h": self._d2h, "ici": self._ici}[kind][device]
+                "d2h": self._d2h}[kind][device]
 
     def _emit(self, device: int, lane: int, cat: str, name: str,
               start: float, dur: float, nbytes: int = 0,
@@ -272,7 +264,7 @@ class EventEngine:
         Returns ``(span, per-task finish times, per-kind link busy
         seconds charged by this batch)``.
         """
-        busy = {"h2d": 0.0, "d2d": 0.0, "d2h": 0.0, "ici": 0.0}
+        busy = {"h2d": 0.0, "d2d": 0.0, "d2h": 0.0}
         if not overlap:
             # fork-join: fetch -> compute -> write-back, task after
             # task, all on lane 0 — nothing ever hides behind compute
@@ -329,15 +321,14 @@ class EventEngine:
         """Acquire the link for one transfer, charge busy seconds and
         emit its span; returns the granted start time.
 
-        A d2d (or neighbor-tier ici) transfer with a known source rides
-        the *serving* device's egress lane (and its span lands on that
-        device's track in the trace): one over-popular holder now
-        serializes its peers' fetches, which is exactly the drain the
-        LRU peer rotation in ``MesixDirectory.peer_holder`` spreads
-        out.  The busy-seconds charge stays with the requesting
-        device's ledger — it is the one whose task waited on the
-        wire."""
-        lane_dev = (x.src if (x.kind in ("d2d", "ici") and x.src >= 0)
+        A d2d transfer with a known source rides the *serving* device's
+        egress lane (and its span lands on that device's track in the
+        trace): one over-popular holder now serializes its peers'
+        fetches, which is exactly the drain the LRU peer rotation in
+        ``MesixDirectory.peer_holder`` spreads out.  The busy-seconds
+        charge stays with the requesting device's ledger — it is the
+        one whose task waited on the wire."""
+        lane_dev = (x.src if (x.kind == "d2d" and x.src >= 0)
                     else device)
         s = self._link(x.kind, lane_dev).acquire(cursor, x.secs)
         busy[x.kind] += x.secs

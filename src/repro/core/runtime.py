@@ -54,7 +54,6 @@ from .tiling import TiledMatrix, TileKey
 # paper Table IV: measured DMA throughputs on Everest
 H2D_BW = 6.54e9   # bytes/s, bidirectional host <-> device
 D2D_BW = 7.80e9   # bytes/s, GPU <-> GPU peer
-ICI_BW = 4.50e10  # bytes/s, per-link inter-chip interconnect (pod tier)
 DEFAULT_PEAK_FLOPS = 1.43e12  # K40c double-precision-ish peak (paper §V-A)
 
 # sentinel payload used by metadata-only runs (execute=False)
@@ -64,47 +63,6 @@ _METADATA_ONLY = np.empty(0)
 def _tile_label(key) -> str:
     """Human-readable tile name for trace spans."""
     return f"{key.matrix_id}[{key.i},{key.j}]"
-
-
-@dataclasses.dataclass(frozen=True)
-class DeviceClass:
-    """What one scheduler "device" *is* (pod tier).
-
-    The paper's runtime schedules over a flat set of accelerators; at
-    pod scale one scheduler device may instead be a whole ICI ring of
-    mesh shards whose compute step is a ring-scheduled SPMD step
-    (``repro.core.distributed``).  The class abstracts exactly the two
-    places the difference matters to the runtime: how fast one
-    "device" computes, and what a fresh host panel costs to scatter
-    across it.  Everything else — ALRU, MESI-X, heap, queues — is
-    class-agnostic.
-    """
-
-    name: str
-    # compute step is a ring-scheduled pod step over `mesh_devices`
-    # shards (core.distributed) rather than a single accelerator kernel
-    ring: bool
-
-    def peak_flops(self, peak: float, mesh_devices: int) -> float:
-        """Effective peak of one scheduler device: a ``mesh_shard``
-        device is a whole ring, so its peak is the per-shard peak
-        times the ring size."""
-        return peak * (mesh_devices if self.ring else 1)
-
-    def hop_bytes(self, nbytes: int, mesh_devices: int) -> int:
-        """ICI bytes one fresh host panel costs to scatter across the
-        ring: a ring all-gather forwards ``(d-1)/d`` of the panel per
-        shard (``ring_allgather_matmul``'s ppermute traffic).  Zero for
-        plain accelerators — their fills never touch ICI."""
-        if not self.ring or mesh_devices <= 1:
-            return 0
-        return nbytes * (mesh_devices - 1) // mesh_devices
-
-
-DEVICE_CLASSES: Dict[str, DeviceClass] = {
-    "accelerator": DeviceClass("accelerator", ring=False),
-    "mesh_shard": DeviceClass("mesh_shard", ring=True),
-}
 
 
 @dataclasses.dataclass
@@ -160,22 +118,6 @@ class RuntimeConfig:
     # change.  Searched by the runtime autotuner alongside tile size,
     # n_streams and policy.
     work_centric: bool = False
-    # --- pod tier (3-level cache: host DRAM -> HBM -> ICI neighbor) ---
-    # what one scheduler "device" is: "accelerator" (the paper's flat
-    # model, bit-and-timing-identical to before this knob existed) or
-    # "mesh_shard" (one device = a whole ICI ring of `mesh_devices`
-    # shards whose compute step is a ring-scheduled pod step from
-    # repro.core.distributed).  See DEVICE_CLASSES.
-    device_class: str = "accelerator"
-    mesh_devices: int = 1                 # ring size per mesh_shard device
-    ici_bw: float = ICI_BW                # bytes/s per ICI link
-    # panel staging (repro.core.task.plan_panel_staged): split
-    # beyond-HBM tasks into panel-sized partials + fix-up so host
-    # panels stream through the tile cache instead of bypassing it.
-    # None derives from the device class (mesh shards stage, plain
-    # accelerators don't); the pod bench forces False for its
-    # direct-host baseline.  Bitwise-identical numerics either way.
-    stage_panels: Optional[bool] = None
     seed: int = 0
 
     def __post_init__(self):
@@ -201,38 +143,6 @@ class RuntimeConfig:
             self.rs_slots = 2 * self.n_streams
         if self.p2p_groups is None:
             self.p2p_groups = [list(range(self.n_devices))]
-        if self.device_class not in DEVICE_CLASSES:
-            raise ValueError(
-                f"unknown device_class {self.device_class!r} "
-                f"(expected one of {sorted(DEVICE_CLASSES)})")
-        if self.ici_bw <= 0:
-            raise ValueError("ici_bw must be positive")
-        if self.dclass.ring:
-            if self.mesh_devices < 2:
-                raise ValueError(
-                    "mesh_shard devices are whole ICI rings: "
-                    "mesh_devices must be >= 2")
-        elif self.mesh_devices != 1:
-            raise ValueError(
-                "mesh_devices != 1 requires device_class='mesh_shard'")
-
-    @property
-    def dclass(self) -> DeviceClass:
-        return DEVICE_CLASSES[self.device_class]
-
-    @property
-    def stage_panels_on(self) -> bool:
-        """Whether run() applies the panel-staging planner; explicit
-        ``stage_panels`` wins, else the device class decides."""
-        if self.stage_panels is not None:
-            return self.stage_panels
-        return self.dclass.ring
-
-    @property
-    def device_peak_flops(self) -> float:
-        """Effective peak of ONE scheduler device (a mesh_shard device
-        is a whole ring — see DeviceClass.peak_flops)."""
-        return self.dclass.peak_flops(self.peak_flops, self.mesh_devices)
 
     @property
     def use_cache(self) -> bool:
@@ -288,9 +198,6 @@ class RuntimeConfig:
             "h2d_bw": self.h2d_bw,
             "d2d_bw": self.d2d_bw,
             "shared_host_link": self.shared_host_link,
-            "device_class": self.device_class,
-            "mesh_devices": self.mesh_devices,
-            "ici_bw": self.ici_bw,
         }
 
 
@@ -438,13 +345,6 @@ class BlasxRuntime:
             tasks = taskmod.plan_work_centric(
                 tasks, {mid: m.grid for mid, m in matrices.items()},
                 self.cfg.n_devices * self.cfg.effective_streams)
-        if self.cfg.stage_panels_on:
-            # pod tier: beyond-HBM tasks become panel-sized partials +
-            # fix-up so host panels stream through the cache hierarchy
-            # (runs after the work-centric planner; both skip non-owner
-            # tasks, so the two compose without double-splitting)
-            tasks = taskmod.plan_panel_staged(tasks, matrices,
-                                              self.cfg.cache_bytes)
         if self.cfg.static_assignment:
             self._queue = None
             self._static_queues = self._static_split(tasks)
@@ -724,7 +624,7 @@ class BlasxRuntime:
                 for rec in recs:
                     comm_s += self._finalize_task(d, rec)
                     compute_each.append(rec.task.flops / (
-                        d.speed * self.cfg.device_peak_flops))
+                        d.speed * self.cfg.peak_flops))
                     d.ledger.tasks += 1
                     d.ledger.flops += rec.task.flops
                     if rec.task.kind == KIND_PARTIAL:
@@ -783,7 +683,6 @@ class BlasxRuntime:
         led.h2d_busy_s += busy["h2d"]
         led.d2d_busy_s += busy["d2d"]
         led.d2h_busy_s += busy["d2h"]
-        led.ici_busy_s += busy["ici"]
         # Fig. 8 "COMM": batch span not covered by an equal amount of
         # compute — the generalization of the lump model's
         # max(0, comm - compute) to a multi-stream schedule.  Capped at
@@ -799,11 +698,6 @@ class BlasxRuntime:
         mode) keeps the seed per-device bandwidth divide."""
         if kind == "d2d":
             return nbytes / self.cfg.d2d_bw
-        if kind == "ici":
-            # every ICI movement charges exactly nbytes/ici_bw, so the
-            # events engine's ici_busy_s == ici_bytes/ici_bw holds by
-            # construction (the pod bench gates this invariant)
-            return nbytes / self.cfg.ici_bw
         if self._engine is not None:
             return nbytes / self.cfg.h2d_bw
         return nbytes / self.cfg.h2d_bw_eff
@@ -817,21 +711,9 @@ class BlasxRuntime:
         comm_s = 0.0
         rec = _TaskExec(task=t, a_tiles=[], b_tiles=[],
                         products=[None] * len(t.steps))
-        # pod tier: a mesh_shard fix-up is a streaming ring-reduce over
-        # the panels its partials staged — it reads each tile once, so
-        # caching them would only displace the warm panels other tasks
-        # are reusing.  Stream the re-gather through the bypass path
-        # (own HBM free, peer ring over ICI, host as last resort)
-        # instead of the ALRU.  Accelerator-class fix-ups keep the
-        # caching gather (bit-and-timing parity with PR 9).
-        streaming = t.kind == KIND_FIXUP and self.cfg.dclass.ring
         for step in t.steps:
-            if streaming:
-                a, s1 = self._bypass_read(d, step.a, rec.xfers)
-                b, s2 = self._bypass_read(d, step.b, rec.xfers)
-            else:
-                a, s1 = self._acquire(d, step.a, acquired, rec.xfers)
-                b, s2 = self._acquire(d, step.b, acquired, rec.xfers)
+            a, s1 = self._acquire(d, step.a, acquired, rec.xfers)
+            b, s2 = self._acquire(d, step.b, acquired, rec.xfers)
             comm_s += s1 + s2
             rec.a_tiles.append(a)
             rec.b_tiles.append(b)
@@ -1007,18 +889,9 @@ class BlasxRuntime:
             if peer is not None:
                 payload = self.devices[peer].store.get(key)
             if payload is not None:  # L2 tile-cache hit: P2P fetch
-                # pod tier: between mesh_shard devices the peer link IS
-                # the ICI fabric — L2 serves ride it at ici_bw and are
-                # ledgered as ici_bytes (d2d stays the PCIe-P2P lane of
-                # plain accelerators), keeping the comm decomposition
-                # exact per device class
-                kind = "ici" if self.cfg.dclass.ring else "d2d"
-                if kind == "ici":
-                    d.ledger.ici_bytes += nbytes
-                else:
-                    d.ledger.d2d_bytes += nbytes
-                secs = self._xfer_secs(kind, nbytes)
-                xfers.append(TimedXfer(kind, nbytes, secs,
+                d.ledger.d2d_bytes += nbytes
+                secs = self._xfer_secs("d2d", nbytes)
+                xfers.append(TimedXfer("d2d", nbytes, secs,
                                        _tile_label(key), src=peer))
                 # egress accounting + LRU rotation on the SERVING side:
                 # the peer's lane is the one being drained, and marking
@@ -1037,7 +910,6 @@ class BlasxRuntime:
                 secs = self._xfer_secs("h2d", nbytes)
                 xfers.append(TimedXfer("h2d", nbytes, secs,
                                        _tile_label(key)))
-                secs += self._ring_hop(d, key, nbytes, xfers)
             d.store[key] = payload
             self.directory.on_fill(key, d.id)
         data = d.store.get(key)
@@ -1047,61 +919,21 @@ class BlasxRuntime:
             d.ledger.h2d_bytes += nbytes
             s2 = self._xfer_secs("h2d", nbytes)
             xfers.append(TimedXfer("h2d", nbytes, s2, _tile_label(key)))
-            s2 += self._ring_hop(d, key, nbytes, xfers)
             secs += s2
         if not self.cfg.execute:
             return data, secs
         return materialize(data, ref), secs
 
-    def _ring_hop(self, d: DeviceSim, key: TileKey, nbytes: int,
-                  xfers: List[TimedXfer]) -> float:
-        """Pod tier: a fresh host panel landing on a mesh_shard device
-        must be scattered across its ICI ring (each shard forwards
-        (mesh-1)/mesh of the bytes — ring_allgather_matmul's ppermute
-        traffic).  Charged once per host fill; warm cache hits and
-        plain accelerators pay nothing."""
-        hop = self.cfg.dclass.hop_bytes(nbytes, self.cfg.mesh_devices)
-        if hop <= 0:
-            return 0.0
-        d.ledger.ici_bytes += hop
-        secs = self._xfer_secs("ici", hop)
-        xfers.append(TimedXfer("ici", hop, secs, _tile_label(key)))
-        return secs
-
     def _bypass_read(self, d: DeviceSim, ref: TileRef,
                      xfers: List[TimedXfer]) -> Tuple[np.ndarray, float]:
         """Uncached read (C_ij inputs / no-cache policies / pinned-full
-        ALRU).  On a mesh_shard device with the L2 directory live this
-        is where the cache hierarchy's THIRD level pays off: if a peer
-        ring holds the tile (a staging partial left the panel warm in
-        its L1), serve it over ICI at ``ici_bw`` instead of re-reading
-        host DRAM — the fix-up join of a beyond-HBM task re-gathers its
-        whole k-loop through this path."""
+        ALRU): charge the host fetch, return the materialized tile."""
         key = ref.key
         mat = self._matrices[key.matrix_id]
         nbytes = mat.nbytes(key.i, key.j)
-        if self.cfg.dclass.ring and self.cfg.use_l2:
-            payload = d.store.get(key)
-            if payload is not None:  # already in this ring's own HBM
-                if not self.cfg.execute:
-                    return _METADATA_ONLY, 0.0
-                return materialize(payload, ref), 0.0
-            peer = self.directory.peer_holder(key, d.id)
-            payload = (self.devices[peer].store.get(key)
-                       if peer is not None else None)
-            if payload is not None:  # neighbor-tier (ICI) hit
-                d.ledger.ici_bytes += nbytes
-                secs = self._xfer_secs("ici", nbytes)
-                xfers.append(TimedXfer("ici", nbytes, secs,
-                                       _tile_label(key), src=peer))
-                self.directory.mark_served(peer)
-                if not self.cfg.execute:
-                    return _METADATA_ONLY, secs
-                return materialize(payload, ref), secs
         d.ledger.h2d_bytes += nbytes
         secs = self._xfer_secs("h2d", nbytes)
         xfers.append(TimedXfer("h2d", nbytes, secs, _tile_label(key)))
-        secs += self._ring_hop(d, key, nbytes, xfers)
         if not self.cfg.execute:
             return _METADATA_ONLY, secs
         return materialize(mat.read_tile(key.i, key.j), ref), secs
@@ -1206,7 +1038,6 @@ class BlasxRuntime:
             "h2d": sum(d.ledger.h2d_bytes for d in self.devices),
             "d2h": sum(d.ledger.d2h_bytes for d in self.devices),
             "d2d": sum(d.ledger.d2d_bytes for d in self.devices),
-            "ici": sum(d.ledger.ici_bytes for d in self.devices),
         }
 
     def makespan(self) -> float:

@@ -18,8 +18,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from .tiling import (TileGrid, TileKey, panel_parts, split_ranges,
-                     workcentric_parts)
+from .tiling import TileGrid, TileKey, split_ranges, workcentric_parts
 
 
 @dataclasses.dataclass
@@ -34,12 +33,6 @@ class Ledger:
     h2d_bytes: int = 0
     d2h_bytes: int = 0
     d2d_bytes: int = 0
-    # pod tier (device_class="mesh_shard"): bytes moved over the ICI
-    # fabric — ring hops scattering freshly-filled host panels across
-    # the shard ring plus neighbor-tier reads (capacity misses served
-    # by a peer's HBM instead of host DRAM).  Together with h2d/d2h/d2d
-    # this decomposes the comm volume exactly.
-    ici_bytes: int = 0
     tasks: int = 0
     steals: int = 0
     flops: int = 0
@@ -57,10 +50,6 @@ class Ledger:
     h2d_busy_s: float = 0.0
     d2d_busy_s: float = 0.0
     d2h_busy_s: float = 0.0
-    # every ICI transfer charges exactly nbytes/ici_bw seconds, so in
-    # the event engine ici_busy_s == ici_bytes/ici_bw by construction
-    # (the pod bench lane gates that equality)
-    ici_busy_s: float = 0.0
     # P2P seconds this device spent *serving* peers' L2 hits from its
     # own store (the egress side of d2d traffic; charged in both time
     # models).  A skew here means one holder is being drained while
@@ -510,9 +499,8 @@ def _split_task(t: Task, n_parts: int, grids: Dict[str, TileGrid],
                 planned: List[Task]) -> int:
     """Carve one owner task into ``n_parts`` contiguous partial-k tasks
     plus the fix-up join, appending them to ``planned``; returns the
-    next free task id.  Shared by the work-centric (Stream-K) and the
-    pod-tier panel-staging planners — both obey the same determinism
-    rule (partials model cost only, the fix-up does the one write)."""
+    next free task id.  The work-centric (Stream-K) planner's split:
+    partials model cost only, the fix-up does the one write."""
     # map deps to the k-steps that read their produced tile, so a
     # partial only waits on the producers of its own k-range; a dep
     # matching no step (defensive) stays on every piece
@@ -545,57 +533,6 @@ def _split_task(t: Task, n_parts: int, grids: Dict[str, TileGrid],
         flops=n_parts * h * w + solve_fl,
         kind=KIND_FIXUP, k_range=(0, len(t.steps))))
     return next_id
-
-
-def plan_panel_staged(tasks: Sequence[Task], matrices: Dict[str, object],
-                      cache_bytes: int) -> List[Task]:
-    """Pod-tier staging planner: cut beyond-HBM tasks into panel-sized
-    partials joined by a fix-up, so host panels stream through the tile
-    cache instead of bypassing it.
-
-    A task whose k-loop input working set exceeds the per-device HBM
-    (``cache_bytes``) cannot keep its tiles resident: every gather past
-    capacity degrades to an uncached host read.  Splitting its k-loop
-    into half-HBM panels that *do* fit
-    (:func:`~repro.core.tiling.panel_parts`) lets each partial
-    stage its panel through the ALRU/MESI-X machinery; the fix-up join
-    then re-reads those panels from the shard ring's HBM over ICI (the
-    hierarchy's third level) rather than from host DRAM.  Numerics are
-    bitwise-identical to the unstaged run for the same reason the
-    work-centric planner's are (see :func:`plan_work_centric` and
-    :func:`_split_task`): partials never write C, the fix-up
-    re-dispatches the full original k-loop.
-
-    ``matrices`` maps matrix id to any object with ``.grid`` and
-    ``.nbytes(i, j)`` (``TiledMatrix`` or ``ShadowMatrix``) so the
-    working set is measured in the matrices' true storage bytes.
-    """
-    tasks = list(tasks)
-    if not tasks or cache_bytes <= 0:
-        return tasks
-    grids = {mid: m.grid for mid, m in matrices.items()}
-    out_key_of = {t.task_id: t.out for t in tasks}
-    next_id = max(t.task_id for t in tasks) + 1
-    planned: List[Task] = []
-    for t in tasks:
-        if t.kind != KIND_OWNER or len(t.steps) < 2:
-            planned.append(t)
-            continue
-        seen = set()
-        total = 0
-        for ref in t.input_refs():
-            if ref.key in seen:
-                continue
-            seen.add(ref.key)
-            total += matrices[ref.key.matrix_id].nbytes(ref.key.i,
-                                                        ref.key.j)
-        n_parts = panel_parts(total, cache_bytes, len(t.steps))
-        if n_parts <= 1:
-            planned.append(t)
-            continue
-        next_id = _split_task(t, n_parts, grids, out_key_of, next_id,
-                              planned)
-    return planned
 
 
 def total_flops(tasks: Sequence[Task]) -> int:

@@ -175,25 +175,6 @@ def workcentric_parts(n_steps: int, n_owner: int, capacity: int,
     return 0
 
 
-def panel_parts(task_bytes: int, cache_bytes: int, n_steps: int) -> int:
-    """How many panel-sized partials the pod-tier staging planner carves
-    from one beyond-HBM task's k-loop (see
-    ``repro.core.task.plan_panel_staged``); 0 leaves the task whole.
-
-    A task whose k-loop input working set (``task_bytes``) fits the
-    device's HBM (``cache_bytes``) keeps its tiles resident through the
-    normal ALRU path and needs no staging.  Truly beyond-HBM tasks are
-    cut into contiguous panels of at most half the HBM each (headroom
-    for a concurrent stream) — ``ceil(task_bytes / (cache_bytes/2))``
-    — capped at one panel per k-step.  Deterministic and purely
-    arithmetic, like :func:`workcentric_parts`.
-    """
-    if cache_bytes <= 0 or n_steps < 2 or task_bytes <= cache_bytes:
-        return 0
-    budget = max(1, cache_bytes // 2)
-    return min(n_steps, -(-task_bytes // budget))
-
-
 def split_ranges(n_steps: int, n_parts: int) -> list:
     """Partition ``range(n_steps)`` into ``n_parts`` contiguous
     ``(start, stop)`` k-ranges whose sizes differ by at most one."""

@@ -70,7 +70,6 @@ _RUNNABLES = {
     "benchmarks/run.py": "benchmarks.run",
     "compare.py": "benchmarks.compare",
     "benchmarks.overlap": "benchmarks.overlap",
-    "benchmarks.pod": "benchmarks.pod",
     "repro.serve": "repro.serve.__main__",
     "repro.analysis": "repro.analysis",
     "chip_smoke.py": "chip_smoke",

@@ -78,18 +78,22 @@ _FOUR_DEVICES = textwrap.dedent("""
 """)
 
 
-@pytest.fixture(scope="module")
-def four_devices():
+def _run_four_devices(script):
     env = dict(os.environ)
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=4 "
                         + env.get("XLA_FLAGS", "")).strip()
     env["PYTHONPATH"] = os.pathsep.join([str(REPO / "src"), str(REPO)])
-    proc = subprocess.run([sys.executable, "-c", _FOUR_DEVICES], cwd=REPO,
+    proc = subprocess.run([sys.executable, "-c", script], cwd=REPO,
                           env=env, capture_output=True, text=True,
                           timeout=300)
     assert proc.returncode == 0, f"{proc.stdout}\n{proc.stderr}"
     line = [ln for ln in proc.stdout.splitlines() if ln.startswith("RESULT ")]
     return json.loads(line[-1][len("RESULT "):])
+
+
+@pytest.fixture(scope="module")
+def four_devices():
+    return _run_four_devices(_FOUR_DEVICES)
 
 
 def test_each_device_runs_its_groups_on_its_own_chip(four_devices):
@@ -116,6 +120,134 @@ def test_four_chips_match_the_float64_reference_in_bf16(four_devices):
 
 def test_four_chips_are_bit_identical_to_one_device_at_f32(four_devices):
     assert four_devices["f32_bit_identical"]
+
+
+# Every Level-3 routine on four placed runtime devices (threads mode, one
+# forced host device each) and on one device, at float32 on ragged
+# shapes: 160 x 224 outputs, k = 200, tile 64.
+_ROUTINES = textwrap.dedent("""
+    import json, time
+    import numpy as np
+    from repro.api import BlasxContext
+    from repro.core import (ref_gemm, ref_symm, ref_syr2k, ref_syrk,
+                            ref_trmm, ref_trsm)
+    from repro.core.runtime import RuntimeConfig
+
+    class Held:
+        def __init__(self, inner):
+            self.inner, self.name = inner, inner.name
+
+        def run_group(self, key, a, b):
+            time.sleep(0.01)
+            return self.inner.run_group(key, a, b)
+
+    rng = np.random.default_rng(11)
+    M, N, K = 160, 224, 200
+
+    def mat(r, c):
+        return rng.standard_normal((r, c)).astype(np.float32)
+
+    def tri(n):  # well conditioned in either triangle
+        return (rng.standard_normal((n, n)) / n
+                + 2.0 * np.eye(n)).astype(np.float32)
+
+    c_sym = mat(M, M)
+    c_sym = (c_sym + c_sym.T) / 2
+    VARIANTS = {
+        "gemm_nn": ("gemm", (mat(M, K), mat(K, N)), {}),
+        "gemm_tt": ("gemm", (mat(K, M), mat(N, K)),
+                    dict(transa="T", transb="T")),
+        "gemm_beta_c": ("gemm", (mat(M, K), mat(K, N), mat(M, N)),
+                        dict(alpha=0.5, beta=-1.5)),
+        "syrk_lower_n": ("syrk", (mat(M, K),), dict(uplo="L")),
+        "syrk_upper_t": ("syrk", (mat(K, M),), dict(uplo="U", trans="T")),
+        "syrk_chol_update": ("syrk", (mat(M, K), c_sym),
+                             dict(alpha=-1.0, beta=1.0, uplo="L")),
+        "syr2k_lower": ("syr2k", (mat(M, K), mat(M, K)), dict(uplo="L")),
+        "symm_left": ("symm", (mat(M, M), mat(M, N)),
+                      dict(side="L", uplo="U")),
+        "symm_right": ("symm", (mat(N, N), mat(M, N)),
+                       dict(side="R", uplo="L")),
+        "trmm_left_lower": ("trmm", (mat(M, M), mat(M, N)),
+                            dict(side="L", uplo="L")),
+        "trmm_right_upper": ("trmm", (mat(N, N), mat(M, N)),
+                             dict(side="R", uplo="U")),
+        "trsm_left_lower": ("trsm", (tri(M), mat(M, N)),
+                            dict(side="L", uplo="L")),
+        "trsm_left_upper_t": ("trsm", (tri(M), mat(M, N)),
+                              dict(side="L", uplo="U", transa="T")),
+        "trsm_right_lower_t": ("trsm", (tri(N), mat(M, N)),
+                               dict(side="R", uplo="L", transa="T")),
+        "trsm_right_upper": ("trsm", (tri(N), mat(M, N)),
+                             dict(side="R", uplo="U")),
+    }
+    REFS = {"gemm": ref_gemm, "syrk": ref_syrk, "syr2k": ref_syr2k,
+            "symm": ref_symm, "trmm": ref_trmm, "trsm": ref_trsm}
+    FOUR = RuntimeConfig(n_devices=4, mode="threads", backend="jax")
+    ONE = RuntimeConfig(n_devices=1, mode="sim", backend="jax")
+
+    def call(cfg, routine, args, kw, tile=64):
+        with BlasxContext(cfg, tile=tile, dtype="float32") as ctx:
+            if cfg.n_devices > 1:
+                ctx.runtime.backend = Held(ctx.runtime.backend)
+            out = getattr(ctx, routine)(*args, **kw).array()
+            return out, ctx.last_call
+
+    result = {}
+    for name, (routine, args, kw) in VARIANTS.items():
+        four, rec = call(FOUR, routine, args, kw)
+        one, _ = call(ONE, routine, args, kw)
+        ref = REFS[routine](*(a.astype(np.float64) for a in args), **kw)
+        result[name] = {
+            "err": float(np.abs(four.astype(np.float64) - ref).max()
+                         / np.abs(ref).max()),
+            "bit_identical": bool(np.array_equal(four, one)),
+            "dtype": str(four.dtype), "d2d_bytes": rec.d2d_bytes}
+    # many tasks sharing each operand tile: peers serve one another
+    a, b = mat(256, 256), mat(256, 256)
+    four, rec = call(FOUR, "gemm", (a, b), {}, tile=32)
+    one, _ = call(ONE, "gemm", (a, b), {}, tile=32)
+    result["l2_serve"] = {"d2d_bytes": rec.d2d_bytes,
+                          "bit_identical": bool(np.array_equal(four, one))}
+    print("RESULT " + json.dumps(result))
+""")
+ROUTINE_VARIANTS = [
+    "gemm_nn", "gemm_tt", "gemm_beta_c",
+    "syrk_lower_n", "syrk_upper_t", "syrk_chol_update", "syr2k_lower",
+    "symm_left", "symm_right", "trmm_left_lower", "trmm_right_upper",
+    "trsm_left_lower", "trsm_left_upper_t", "trsm_right_lower_t",
+    "trsm_right_upper",
+]
+# float32 against the float64 reference, relative to the largest entry:
+# k = 200 (or a 224-long TRSM chain) of float32 roundings stays near
+# 3e-7 on these inputs; 1e-5 leaves room without hiding a wrong tile
+F32_TOL = 1e-5
+
+
+@pytest.fixture(scope="module")
+def routines_on_four_devices():
+    return _run_four_devices(_ROUTINES)
+
+
+@pytest.mark.parametrize("variant", ROUTINE_VARIANTS)
+def test_four_devices_match_the_float64_reference(routines_on_four_devices,
+                                                  variant):
+    got = routines_on_four_devices[variant]
+    assert got["dtype"] == "float32"
+    assert got["err"] <= F32_TOL, got
+
+
+@pytest.mark.parametrize("variant", ROUTINE_VARIANTS)
+def test_four_devices_are_bit_identical_to_one_device(
+        routines_on_four_devices, variant):
+    assert routines_on_four_devices[variant]["bit_identical"]
+
+
+def test_an_l2_serve_between_devices_gives_the_same_bits(
+        routines_on_four_devices):
+    got = routines_on_four_devices["l2_serve"]
+    assert got["d2d_bytes"] > 0
+    assert got["bit_identical"]
 
 
 def test_more_devices_than_chips_wrap_round_them():

@@ -569,12 +569,16 @@ def test_threads_crash_leaves_no_stranded_tasks():
     rt = BlasxRuntime(RuntimeConfig(n_devices=2, mode="threads",
                                     cache_bytes=32 << 20))
     orig = rt._execute_batch
+    crashed = threading.Event()
 
     def boom(d, batch):
         if d.id == 1:
+            crashed.set()
             raise RuntimeError("injected-crash")
-        time.sleep(0.005)   # keep the healthy device slow enough that
-        return orig(d, batch)  # the crash lands with work still queued
+        # the healthy device runs nothing before the crash, so the crash
+        # lands with work still queued however the threads are timed
+        crashed.wait(timeout=10)
+        return orig(d, batch)
 
     rt._execute_batch = boom
     t0 = time.perf_counter()
@@ -597,12 +601,16 @@ def test_failed_batch_releases_acquired_readers():
     rt = BlasxRuntime(RuntimeConfig(n_devices=2, mode="threads",
                                     cache_bytes=32 << 20))
     orig = rt._dispatch_steps
+    crashed = threading.Event()
 
     def boom(d, recs):
         if d.id == 1:
+            crashed.set()
             raise RuntimeError("dispatch-crash")   # after gather
-        time.sleep(0.002)   # keep the healthy device slow enough that
-        return orig(d, recs)  # the crashing one always gets a batch
+        # the healthy device dispatches nothing before the crash, so it
+        # cannot drain the queue before the crashing one gets a batch
+        crashed.wait(timeout=10)
+        return orig(d, recs)
 
     rt._dispatch_steps = boom
     with pytest.raises(RuntimeError, match="dispatch-crash"):
